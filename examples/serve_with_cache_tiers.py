@@ -20,7 +20,6 @@ from repro.core import (BucketMount, ClientConfig, Cluster, ObjcacheClient,
                         ObjcacheFS, ServerConfig)
 from repro.models import build_model
 from repro.serving import ModelStore, ServingEngine
-from repro.train import train_state_init
 
 workdir = tempfile.mkdtemp(prefix="objcache-serve-")
 try:
@@ -35,11 +34,11 @@ try:
 
     cfg = get_reduced("qwen3-0.6b")
     model = build_model(cfg)
-    state, _ = train_state_init(model, jax.random.PRNGKey(0), max_seq=64)
+    params, _ = model.init(jax.random.PRNGKey(0))
 
     # publish: a trainer saves the model durably (lands in COS)
     pub = fs_on("n0")
-    CheckpointManager(pub, "/models/qwen3-tiny").save(0, state.params,
+    CheckpointManager(pub, "/models/qwen3-tiny").save(0, params,
                                                       durable=True)
     # wipe the cluster (fresh serving fleet, cold caches)
     for nm in list(cluster.node_list()):
@@ -54,21 +53,21 @@ try:
         fs = fs_on_2(node)
         store = ModelStore(fs, "/models/qwen3-tiny")
         t0 = cluster2.clock.now
-        params, nbytes = store.load(0, like=state.params)
-        return params, nbytes, cluster2.clock.now - t0
+        loaded, nbytes = store.load(0, like=params)
+        return loaded, nbytes, cluster2.clock.now - t0
 
     def fs_on_2(node):
         return ObjcacheFS(ObjcacheClient(
             cluster2.router, cluster2.clock, node,
             ClientConfig(consistency="weak"), chunk_size=1 << 20))
 
-    params, nbytes, t_cold = load_on("n0")      # replica 1: COS miss
+    served, nbytes, t_cold = load_on("n0")      # replica 1: COS miss
     _, _, t_cluster = load_on("n1")             # replica 2: cluster tier
     _, _, t_node = load_on("n1")                # replica 2 restart: node tier
     print(f"model {nbytes / 1e6:.1f} MB | cold {t_cold:.3f}s | "
           f"cluster {t_cluster:.3f}s | node {t_node:.3f}s (virtual)")
 
-    engine = ServingEngine(build_model(cfg), params, max_len=64)
+    engine = ServingEngine(build_model(cfg), served, max_len=64)
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, cfg.vocab, size=6, dtype=np.int32)
                for _ in range(4)]

@@ -26,12 +26,10 @@ import numpy as np
 from ..core.fs import ObjcacheFS
 
 
-def _flatten(tree) -> dict[str, np.ndarray]:
-    flat = {}
+def _flatten(tree):
+    """(flat key, leaf) pairs; leaves stay where they are (host or device)."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        key = ".".join(_key_str(k) for k in path)
-        flat[key] = np.asarray(leaf)
-    return flat
+        yield ".".join(_key_str(k) for k in path), leaf
 
 
 def _key_str(k) -> str:
@@ -51,9 +49,11 @@ class CheckpointManager:
     def save(self, step: int, tree, durable: bool = False) -> dict:
         d = f"{self.root}/step_{step}"
         self.fs.makedirs(d)
-        flat = _flatten(tree)
         manifest = {"step": step, "leaves": {}}
-        for key, arr in flat.items():
+        # one leaf at a time on the host: a full-width train state is
+        # several GB, and it stays on the device
+        for key, leaf in _flatten(tree):
+            arr = np.asarray(leaf)
             path = f"{d}/{key}.bin"
             self.fs.write_file(path, arr.tobytes())
             manifest["leaves"][key] = {"shape": list(arr.shape),
@@ -100,8 +100,8 @@ class CheckpointManager:
         for path, leaf in leaves_like:
             key = ".".join(_key_str(k) for k in path)
             arr = flat[key]
-            rebuilt.append(arr.astype(leaf.dtype) if hasattr(leaf, "dtype")
-                           else arr)
+            rebuilt.append(arr.astype(leaf.dtype, copy=False)
+                           if hasattr(leaf, "dtype") else arr)
         treedef = jax.tree_util.tree_structure(like)
         return jax.tree_util.tree_unflatten(treedef, rebuilt)
 

@@ -14,7 +14,8 @@ state is carried.  TPU adaptation:
 * decay products use log-space cumulative sums for stability (exp of
   differences instead of products of many a_t < 1).
 
-Layouts: x (BH, S, P); loga (BH, S); b, c (BH, S, N).  float32 math.
+Layouts: x (BH, S, P); loga (BH, 1, S), so its (1, chunk) block keeps the
+sequence on lanes; b, c (BH, S, N).  float32 math.
 """
 
 from __future__ import annotations
@@ -37,33 +38,37 @@ def _ssd_kernel(x_ref, loga_ref, b_ref, c_ref, y_ref, hfin_ref, h_scr, *,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[0].astype(jnp.float32)          # (L, P)
-    loga = loga_ref[0].astype(jnp.float32)    # (L,)
+    loga = loga_ref[0].astype(jnp.float32)    # (1, L) lane-major row
     bmat = b_ref[0].astype(jnp.float32)       # (L, N)
     cmat = c_ref[0].astype(jnp.float32)       # (L, N)
     h = h_scr[...]                            # (N, P)
 
-    cum = jnp.cumsum(loga)                    # cum[i] = sum_{t<=i} log a_t
-    total = cum[-1]
-
-    # intra-chunk: y_i += sum_{j<=i} (c_i · b_j) exp(cum_i - cum_j) x_j
-    gmat = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)  # (L, L)
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.exp(cum[:, None] - cum[None, :])
-    dmask = jnp.where(lj <= li, decay, 0.0)
+    causal = lj <= li
+    # cum[i] = sum_{t<=i} log a_t as an (L, 1) column: a masked lane
+    # reduction (Mosaic has no cumsum lowering)
+    cum = jnp.sum(jnp.where(causal, loga, 0.0), axis=1, keepdims=True)
+    total = jnp.sum(loga, axis=1, keepdims=True)             # (1, 1)
+
+    # intra-chunk: y_i += sum_{j<=i} (c_i · b_j) exp(cum_i - cum_j) x_j;
+    # cum_j along lanes is the (L, L) transpose of cum_i broadcast
+    gmat = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)  # (L, L)
+    cum_i = jnp.broadcast_to(cum, (chunk, chunk))
+    dmask = jnp.where(causal, jnp.exp(cum_i - cum_i.T), 0.0)
     y = jax.lax.dot_general(gmat * dmask, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk: y_i += exp(cum_i) * (c_i · h_prev)
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum) * jax.lax.dot_general(
         cmat, h, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     # state update: h = exp(total) h_prev + sum_j exp(total - cum_j) b_j x_jᵀ
-    w = jnp.exp(total - cum)                  # (L,)
+    w = jnp.exp(total - cum)                  # (L, 1)
     h_new = jnp.exp(total) * h + jax.lax.dot_general(
-        bmat * w[:, None], x, (((0,), (0,)), ((), ())),
+        bmat * w, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)   # (N, P)
     h_scr[...] = h_new
     y_ref[0] = y.astype(y_ref.dtype)
@@ -87,15 +92,15 @@ def ssd_scan_pallas(x: jax.Array, loga: jax.Array, b: jax.Array,
     nc = S // chunk
 
     xr = jnp.moveaxis(x, 2, 1).reshape(B * H, S, P)
-    lr = jnp.moveaxis(loga, 2, 1).reshape(B * H, S)
+    lr = jnp.moveaxis(loga, 2, 1).reshape(B * H, 1, S)
     br = jnp.moveaxis(b, 2, 1).reshape(B * H, S, N)
     cr = jnp.moveaxis(c, 2, 1).reshape(B * H, S, N)
 
     def seq_map(bh, ci):
         return (bh, ci, 0)
 
-    def vec_map(bh, ci):
-        return (bh, ci)
+    def row_map(bh, ci):
+        return (bh, 0, ci)
 
     def fin_map(bh, ci):
         return (bh, 0, 0)
@@ -106,7 +111,7 @@ def ssd_scan_pallas(x: jax.Array, loga: jax.Array, b: jax.Array,
         grid=(B * H, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, P), seq_map),
-            pl.BlockSpec((1, chunk), vec_map),
+            pl.BlockSpec((1, 1, chunk), row_map),
             pl.BlockSpec((1, chunk, N), seq_map),
             pl.BlockSpec((1, chunk, N), seq_map),
         ],

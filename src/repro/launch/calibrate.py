@@ -25,8 +25,7 @@ import sys
 
 from ..configs import ARCH_IDS, SHAPES, get_config
 from ..models.lm import n_periods, period_length
-from .dryrun import collective_bytes, cost_analysis_dict, lower_cell
-from .mesh import set_mesh
+from .dryrun import collective_bytes
 
 CAL_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "reports", "calibration")
@@ -62,7 +61,7 @@ def _measure(arch: str, shape_name: str, k_periods: int) -> dict | None:
     from ..parallel import ctx
     ctx.set_from_mesh(mesh, rules)
     specs = input_specs(cfg_small, shape, model)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             state, spec = abstract_state(model, shape.seq_len, with_opt=True)
             state_sh = shardings_for_state(state, spec, mesh, rules)
@@ -94,7 +93,7 @@ def _measure(arch: str, shape_name: str, k_periods: int) -> dict | None:
                               ).lower(params, specs["token"],
                                       specs["cache"], specs["cache_len"])
     compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),

@@ -35,7 +35,7 @@ from ..configs import ARCH_IDS, SHAPES, get_config
 from ..models import build_model
 from ..optim import AdamWConfig
 from ..train.steps import make_decode_step, make_train_step
-from .mesh import make_production_mesh, set_mesh
+from .mesh import make_production_mesh
 from .specs import (abstract_state, input_specs, shardings_for_batch,
                     shardings_for_decode, shardings_for_state)
 from ..parallel import default_rules
@@ -62,15 +62,6 @@ TRAIN_TUNING: dict[str, dict] = {
     "h2o-danube-3-4b": {"accum_steps": 2},
     "whisper-tiny": {"accum_steps": 16},
 }
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """Version-portable `compiled.cost_analysis()`: jax <= 0.4.x returns a
-    one-element list of dicts (per program), newer jax returns the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 
 def collective_bytes(hlo_text: str) -> dict[str, int]:
@@ -127,7 +118,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     specs = input_specs(cfg, shape, model)
 
     max_seq = shape.seq_len
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             state, spec = abstract_state(model, max_seq, with_opt=True)
             state_sh = shardings_for_state(state, spec, mesh, rules)
@@ -180,7 +171,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     n_dev = mesh.devices.size
     flops = float(cost.get("flops", 0.0))

@@ -14,23 +14,6 @@ from __future__ import annotations
 import jax
 
 
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Version-portable `with set_mesh(mesh): ...` context.
-
-    `jax.set_mesh` appeared in jax 0.6 (and `jax.sharding.use_mesh` briefly
-    before it); on 0.4.x/0.5.x neither exists and the `Mesh` object itself is
-    the context manager that installs the physical mesh for jit/shard_map.
-    All three behave identically for our dry-run/calibration lowering, which
-    only needs the mesh active while tracing."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
