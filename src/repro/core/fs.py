@@ -24,6 +24,7 @@ from __future__ import annotations
 import posixpath
 
 from .client import ObjcacheClient, _Handle
+from .spans import span
 from .types import Errno, FSError, InodeKind, ROOT_INODE
 
 
@@ -78,13 +79,14 @@ class ObjcacheFS:
         return self.client.getattr(self.resolve(path))
 
     def exists(self, path: str) -> bool:
-        try:
-            self.resolve(path)
-            return True
-        except FSError as e:
-            if e.errno in (Errno.ENOENT, Errno.ENOTDIR):
-                return False
-            raise
+        with span("fs.exists"):
+            try:
+                self.resolve(path)
+                return True
+            except FSError as e:
+                if e.errno in (Errno.ENOENT, Errno.ENOTDIR):
+                    return False
+                raise
 
     def listdir(self, path: str) -> list[str]:
         ino = self.resolve(path)
@@ -112,13 +114,14 @@ class ObjcacheFS:
         self.unlink(path)
 
     def rename(self, src: str, dst: str) -> None:
-        sp, sn = self.resolve_parent(src)
-        dp, dn = self.resolve_parent(dst)
-        ino = self.client.lookup(sp, sn)
-        if self.exists(dst):
-            self.unlink(dst)
-        _, new_key = self._cos_target(dst)
-        self.client.rename(sp, sn, dp, dn, ino, new_key)
+        with span("fs.rename"):
+            sp, sn = self.resolve_parent(src)
+            dp, dn = self.resolve_parent(dst)
+            ino = self.client.lookup(sp, sn)
+            if self.exists(dst):
+                self.unlink(dst)
+            _, new_key = self._cos_target(dst)
+            self.client.rename(sp, sn, dp, dn, ino, new_key)
 
     def truncate(self, path: str, size: int) -> None:
         self.client.truncate(self.resolve(path), size)
@@ -169,9 +172,11 @@ class ObjcacheFS:
         if cl.cfg.consistency == "strict":
             # read-after-write: reflect immediately in cluster-local cache
             seq = cl.next_seq()
-            staged = cl.write_chunks(h.ino, off, data, seq)
+            with span("fs.write.stage"):
+                staged = cl.write_chunks(h.ino, off, data, seq)
             new_size = max(h.size_hint, off + len(data))
-            cl.flush_write(h.ino, staged, new_size, seq)
+            with span("fs.write.commit"):
+                cl.flush_write(h.ino, staged, new_size, seq)
             h.size_hint = new_size
             cl.invalidate_ino(h.ino)
         else:
@@ -202,11 +207,15 @@ class ObjcacheFS:
                 runs.append((off, bytearray(data)))
         seq = cl.next_seq()
         staged_all: dict[int, list[str]] = {}
-        for off, data in runs:
-            for coff, ids in cl.write_chunks(h.ino, off, bytes(data), seq):
-                staged_all.setdefault(coff, []).extend(ids)
-            seq = cl.next_seq()
-        cl.flush_write(h.ino, sorted(staged_all.items()), h.size_hint, seq)
+        with span("fs.write.stage"):
+            for off, data in runs:
+                for coff, ids in cl.write_chunks(h.ino, off, bytes(data),
+                                                 seq):
+                    staged_all.setdefault(coff, []).extend(ids)
+                seq = cl.next_seq()
+        with span("fs.write.commit"):
+            cl.flush_write(h.ino, sorted(staged_all.items()), h.size_hint,
+                           seq)
         h.buffer.clear()
         h.buffered_bytes = 0
         cl.invalidate_ino(h.ino)
@@ -220,7 +229,8 @@ class ObjcacheFS:
             if h.buffer:
                 self._flush_buffer(h)  # read-your-own-writes within a handle
             meta = cl.getattr(h.ino, cached_ok=True)
-        return cl.read_range(h.ino, off, length, meta, handle=h)
+        with span("fs.read"):
+            return cl.read_range(h.ino, off, length, meta, handle=h)
 
     def fsync(self, fh: int) -> None:
         h = self._h(fh)

@@ -13,8 +13,8 @@ default payload sizes).  `Router.register` collects each server's handler
 table once, and `Router.rpc` dispatches through it — an unregistered method
 name is a programming error (`UnknownRpcError`), not a silent `getattr`.
 The router also records per-method call counts, bytes, and virtual-time
-latency, both globally (`Router.method_stats`) and into the destination
-server's `stats` dict (`rpc.<method>.calls/bytes/vtime`).
+latency globally (`Router.method_stats`), and a per-method call count into
+the destination server's `stats` dict (`rpc.<method>.calls`).
 
 Admission control (QoS) lives at this layer too, because the envelope is
 the unit the fabric can police without understanding filesystem
@@ -190,7 +190,7 @@ class Router:
         # per-method: calls / bytes / vtime (summed reply latency) /
         # timeouts (unreachable dst) / errors (handler raised)
         self.method_stats: dict[str, dict[str, float]] = {}
-        self._skeys: dict[str, tuple[str, str, str]] = {}
+        self._calls_keys: dict[str, str] = {}
         # per-tenant QoS admission at the fabric edge (None = everything
         # admitted); tenant_stats: admitted / delayed / shed / delay_s
         self.admission: AdmissionControl | None = None
@@ -292,13 +292,11 @@ class Router:
             self.method_stats[method] = st
         return st
 
-    def _stat_keys(self, method: str) -> tuple[str, str, str]:
-        keys = self._skeys.get(method)
-        if keys is None:
-            keys = (f"rpc.{method}.calls", f"rpc.{method}.bytes",
-                    f"rpc.{method}.vtime")
-            self._skeys[method] = keys
-        return keys
+    def _calls_key(self, method: str) -> str:
+        key = self._calls_keys.get(method)
+        if key is None:
+            key = self._calls_keys[method] = f"rpc.{method}.calls"
+        return key
 
     def rpc(self, src: str | None, dst: str, method: str, start: float,
             nbytes_out: int | None = None, nbytes_in: int | None = None,
@@ -354,11 +352,9 @@ class Router:
         mstat["calls"] += 1
         mstat["bytes"] += n_total
         mstat["vtime"] += latency
-        k_calls, k_bytes, k_vtime = self._stat_keys(method)
+        k_calls = self._calls_key(method)
         sstats = server.stats
         sstats[k_calls] = sstats.get(k_calls, 0) + 1
-        sstats[k_bytes] = sstats.get(k_bytes, 0) + n_total
-        sstats[k_vtime] = sstats.get(k_vtime, 0.0) + latency
         return result, back
 
     def rpc_batch(self, src: str | None, dst: str, calls: list[dict],
@@ -435,10 +431,8 @@ class Router:
             mstat["bytes"] += n_total
             mstat["vtime"] += latency
             mstat["batched"] = mstat.get("batched", 0) + (len(calls) > 1)
-            k_calls, k_bytes, k_vtime = self._stat_keys(c["method"])
+            k_calls = self._calls_key(c["method"])
             sstats[k_calls] = sstats.get(k_calls, 0) + 1
-            sstats[k_bytes] = sstats.get(k_bytes, 0) + n_total
-            sstats[k_vtime] = sstats.get(k_vtime, 0.0) + latency
         return results, back
 
     def charge_timeout(self, start: float) -> float:

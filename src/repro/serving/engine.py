@@ -28,6 +28,7 @@ import numpy as np
 
 from ..checkpoint.manager import CheckpointManager
 from ..core.fs import ObjcacheFS
+from ..core.spans import span
 from ..models import Model
 
 
@@ -100,7 +101,11 @@ class ServingEngine:
         self.params = params
         self.max_len = max_len
         self.kvstore = kvstore
-        self._decode = jax.jit(model.decode)
+
+        def decode_step(params, tok, cache, cache_len):
+            return model.decode(params, tok, cache, cache_len)
+        # a named function, so the trace names the module jit_decode_step
+        self._decode = jax.jit(decode_step)
         self._prefill_tok = jax.jit(
             lambda p, b: model.prefill(p, b))
 
@@ -118,21 +123,24 @@ class ServingEngine:
         # exact for every arch family, incl. ring buffers and SSM state)
         cache_len = jnp.int32(0)
         logits = None
-        for t in range(plen):
-            logits, cache = self._decode(self.params,
-                                         jnp.asarray(toks[:, t:t + 1]),
-                                         cache, cache_len)
-            cache_len = cache_len + 1
+        with span("engine.prefill"):
+            for t in range(plen):
+                logits, cache = self._decode(self.params,
+                                             jnp.asarray(toks[:, t:t + 1]),
+                                             cache, cache_len)
+                cache_len = cache_len + 1
         outs: list[list[int]] = [[] for _ in range(b)]
-        tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        for i in range(b):
-            outs[i].append(int(tok[i, 0]))
-        for _ in range(max_new - 1):
-            logits, cache = self._decode(self.params, tok, cache, cache_len)
-            cache_len = cache_len + 1
+        with span("engine.decode"):
             tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
             for i in range(b):
                 outs[i].append(int(tok[i, 0]))
+            for _ in range(max_new - 1):
+                logits, cache = self._decode(self.params, tok, cache,
+                                             cache_len)
+                cache_len = cache_len + 1
+                tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+                for i in range(b):
+                    outs[i].append(int(tok[i, 0]))
         return outs
 
     def generate_with_reuse(self, prompt: np.ndarray, max_new: int = 8,
@@ -162,13 +170,16 @@ class ServingEngine:
                 "kv_read_bytes": 0, "kv_stored": 0}
         kv = self.kvstore
         if kv is not None:
-            hit = kv.lookup(prompt, cap=s - 1)
+            with span("engine.lookup"):
+                hit = kv.lookup(prompt, cap=s - 1)
             if hit is not None:
                 start, key = hit
-                restored, man = kv.get(key, like=cache)
-                cache = jax.tree.map(
-                    lambda like_leaf, a: jnp.asarray(a, like_leaf.dtype),
-                    cache, restored)
+                with span("engine.restore"):
+                    restored, man = kv.get(key, like=cache)
+                    with span("engine.restore.to_device"):
+                        cache = jax.tree.map(
+                            lambda like, a: jnp.asarray(a, like.dtype),
+                            cache, restored)
                 info.update(reused_len=start, exact_hit=(start == s - 1),
                             kv_read_bytes=man["nbytes"])
         cache_len = jnp.int32(start)
@@ -176,21 +187,24 @@ class ServingEngine:
         logits = None
         snap_lens = set(kv.snapshot_lens(s)) if (kv is not None and store) \
             else ()
-        for t in range(start, s):
-            logits, cache = self._decode(self.params,
-                                         jnp.asarray(toks[:, t:t + 1]),
-                                         cache, cache_len)
-            cache_len = cache_len + 1
-            info["prefill_steps"] += 1
-            if (t + 1) in snap_lens and (t + 1) > start:
-                if kv.put(prompt[:t + 1], cache) is not None:
-                    info["kv_stored"] += 1
+        with span("engine.prefill"):
+            for t in range(start, s):
+                logits, cache = self._decode(self.params,
+                                             jnp.asarray(toks[:, t:t + 1]),
+                                             cache, cache_len)
+                cache_len = cache_len + 1
+                info["prefill_steps"] += 1
+                if (t + 1) in snap_lens and (t + 1) > start:
+                    if kv.put(prompt[:t + 1], cache) is not None:
+                        info["kv_stored"] += 1
         out: list[int] = []
-        tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        out.append(int(tok[0, 0]))
-        for _ in range(max_new - 1):
-            logits, cache = self._decode(self.params, tok, cache, cache_len)
-            cache_len = cache_len + 1
+        with span("engine.decode"):
             tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
             out.append(int(tok[0, 0]))
+            for _ in range(max_new - 1):
+                logits, cache = self._decode(self.params, tok, cache,
+                                             cache_len)
+                cache_len = cache_len + 1
+                tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+                out.append(int(tok[0, 0]))
         return out, info

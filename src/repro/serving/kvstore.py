@@ -52,6 +52,7 @@ import json
 import numpy as np
 
 from ..core.fs import ObjcacheFS
+from ..core.spans import span
 
 _BLOCKS = "blocks.bin"
 _MANIFEST = "manifest.json"
@@ -180,33 +181,43 @@ class KVCacheStore:
             return None
         key = prefix_key(tokens)
         d = self._dir(key)
-        if self.fs.exists(f"{d}/{_MANIFEST}"):
-            self.stats["dup_puts"] += 1
-            return None
-        segs: list[dict] = []
-        blobs: list[bytes] = []
-        off = 0
-        for leaf, arr in _flat_items(cache):
-            arr = np.asarray(arr)
-            if arr.ndim < 2 or batch_index >= arr.shape[1]:
-                raise ValueError(f"cache leaf {leaf!r}: expected "
-                                 f"(n_periods, batch, ...), got {arr.shape}")
-            row = np.ascontiguousarray(arr[:, batch_index])
-            for p in range(row.shape[0]):          # one block per layer period
-                raw = np.ascontiguousarray(row[p]).tobytes()
-                segs.append({"leaf": leaf, "period": p, "offset": off,
-                             "nbytes": len(raw), "dtype": str(arr.dtype),
-                             "shape": list(row.shape[1:])})
-                blobs.append(raw)
-                off += len(raw)
-        self.fs.makedirs(d)
-        self.fs.write_file(f"{d}/{_BLOCKS}", b"".join(blobs))
-        manifest = {"key": key, "tokens": tokens.tolist(),
-                    "cache_len": int(tokens.size), "nbytes": off,
-                    "n_blocks": len(segs), "layers": segs}
-        tmp = f"{d}/.manifest.tmp"
-        self.fs.write_file(tmp, json.dumps(manifest).encode())
-        self.fs.rename(tmp, f"{d}/{_MANIFEST}")   # 2PC publish point
+        with span("kv.put"):
+            with span("kv.put.fs"):
+                stored = self.fs.exists(f"{d}/{_MANIFEST}")
+            if stored:
+                self.stats["dup_puts"] += 1
+                return None
+            with span("kv.put.to_host"):
+                host = [(leaf, np.asarray(arr))
+                        for leaf, arr in _flat_items(cache)]
+            with span("kv.put.pack"):
+                segs: list[dict] = []
+                blobs: list[bytes] = []
+                off = 0
+                for leaf, arr in host:
+                    if arr.ndim < 2 or batch_index >= arr.shape[1]:
+                        raise ValueError(
+                            f"cache leaf {leaf!r}: expected "
+                            f"(n_periods, batch, ...), got {arr.shape}")
+                    row = np.ascontiguousarray(arr[:, batch_index])
+                    for p in range(row.shape[0]):  # one block per layer period
+                        raw = np.ascontiguousarray(row[p]).tobytes()
+                        segs.append({"leaf": leaf, "period": p,
+                                     "offset": off, "nbytes": len(raw),
+                                     "dtype": str(arr.dtype),
+                                     "shape": list(row.shape[1:])})
+                        blobs.append(raw)
+                        off += len(raw)
+                blocks = b"".join(blobs)
+            with span("kv.put.fs"):
+                self.fs.makedirs(d)
+                self.fs.write_file(f"{d}/{_BLOCKS}", blocks)
+                manifest = {"key": key, "tokens": tokens.tolist(),
+                            "cache_len": int(tokens.size), "nbytes": off,
+                            "n_blocks": len(segs), "layers": segs}
+                tmp = f"{d}/.manifest.tmp"
+                self.fs.write_file(tmp, json.dumps(manifest).encode())
+                self.fs.rename(tmp, f"{d}/{_MANIFEST}")   # 2PC publish point
         self.stats["puts"] += 1
         self.stats["put_bytes"] += off
         return manifest
@@ -247,41 +258,46 @@ class KVCacheStore:
         (e.g. ``{"slot0/k"}``); each selected segment is one ranged read,
         the layerwise-retrieval pattern of ObjectCache.  Returns
         ``(cache, manifest)``."""
-        man = self.manifest(key)
-        path = f"{self._dir(key)}/{_BLOCKS}"
-        per_leaf: dict[str, dict[int, np.ndarray]] = {}
-        want = [s for s in man["layers"]
-                if layers is None or s["leaf"] in layers]
-        if layers is None:
-            # whole-prefix restore: one sequential read of the blocks file
-            raw_all = self.fs.read_file(path)
-            raws = [raw_all[s["offset"]:s["offset"] + s["nbytes"]]
-                    for s in want]
-        else:
-            raws = [self.fs.read_file_range(path, s["offset"], s["nbytes"])
-                    for s in want]
-        for seg, raw in zip(want, raws):
-            if len(raw) != seg["nbytes"]:
-                raise ValueError(
-                    f"kv block {seg['leaf']}[{seg['period']}] of {key}: "
-                    f"read {len(raw)} bytes, manifest says {seg['nbytes']}")
-            arr = np.frombuffer(raw, dtype=seg["dtype"]).reshape(seg["shape"])
-            per_leaf.setdefault(seg["leaf"], {})[seg["period"]] = arr
-            self.stats["get_bytes"] += len(raw)
-        self.stats["gets"] += 1
-        like_flat = dict(_flat_items(like)) if like is not None else {}
-        leaves: dict[str, np.ndarray] = {}
-        for leaf, periods in per_leaf.items():
-            stacked = np.stack([periods[p] for p in sorted(periods)])
-            tgt = like_flat.get(leaf)
-            if tgt is not None:
-                tgt = np.asarray(tgt)
-                if str(tgt.dtype) != str(stacked.dtype):
+        with span("kv.get.fs"):
+            man = self.manifest(key)
+            path = f"{self._dir(key)}/{_BLOCKS}"
+            want = [s for s in man["layers"]
+                    if layers is None or s["leaf"] in layers]
+            if layers is None:
+                # whole-prefix restore: one sequential read of the blocks file
+                raw_all = self.fs.read_file(path)
+            else:
+                raws = [self.fs.read_file_range(path, s["offset"],
+                                                s["nbytes"]) for s in want]
+        with span("kv.get.assemble"):
+            if layers is None:
+                raws = [raw_all[s["offset"]:s["offset"] + s["nbytes"]]
+                        for s in want]
+            per_leaf: dict[str, dict[int, np.ndarray]] = {}
+            for seg, raw in zip(want, raws):
+                if len(raw) != seg["nbytes"]:
                     raise ValueError(
-                        f"kv block {leaf!r}: stored dtype {stacked.dtype} "
-                        f"!= cache dtype {tgt.dtype}")
-                # target per-row shape: drop the batch axis
-                row_shape = (tgt.shape[0],) + tuple(tgt.shape[2:])
-                stacked = _fit(stacked, row_shape, man["cache_len"], leaf)
-            leaves[leaf] = stacked[:, None]        # re-insert batch axis
-        return _unflatten(leaves), man
+                        f"kv block {seg['leaf']}[{seg['period']}] of {key}: "
+                        f"read {len(raw)} bytes, manifest says "
+                        f"{seg['nbytes']}")
+                arr = np.frombuffer(raw, dtype=seg["dtype"]).reshape(
+                    seg["shape"])
+                per_leaf.setdefault(seg["leaf"], {})[seg["period"]] = arr
+                self.stats["get_bytes"] += len(raw)
+            self.stats["gets"] += 1
+            like_flat = dict(_flat_items(like)) if like is not None else {}
+            leaves: dict[str, np.ndarray] = {}
+            for leaf, periods in per_leaf.items():
+                stacked = np.stack([periods[p] for p in sorted(periods)])
+                tgt = like_flat.get(leaf)
+                if tgt is not None:
+                    tgt = np.asarray(tgt)
+                    if str(tgt.dtype) != str(stacked.dtype):
+                        raise ValueError(
+                            f"kv block {leaf!r}: stored dtype "
+                            f"{stacked.dtype} != cache dtype {tgt.dtype}")
+                    # target per-row shape: drop the batch axis
+                    row_shape = (tgt.shape[0],) + tuple(tgt.shape[2:])
+                    stacked = _fit(stacked, row_shape, man["cache_len"], leaf)
+                leaves[leaf] = stacked[:, None]        # re-insert batch axis
+            return _unflatten(leaves), man
