@@ -8,6 +8,7 @@ by train/serve/launch:
     logits       = model.prefill(params, batch)
     logits, c2   = model.decode(params, token, cache, cache_len)
     spec_tree    = model.cache_spec(batch_size, max_len)
+    axes_tree    = model.cache_pos_axes()   # per leaf: position axis or None
 """
 
 from __future__ import annotations
@@ -30,6 +31,7 @@ class Model:
     decode: Callable[..., tuple[jax.Array, dict]]
     cache_spec: Callable[..., dict]
     init_cache: Callable[..., dict]
+    cache_pos_axes: Callable[[], dict]
 
 
 def build_model(cfg: ArchConfig) -> Model:
@@ -44,6 +46,7 @@ def build_model(cfg: ArchConfig) -> Model:
                                                              cache, n),
             cache_spec=lambda b, s: encdec.cache_spec(cfg, b, s),
             init_cache=lambda b, s: encdec.init_cache(cfg, b, s),
+            cache_pos_axes=lambda: encdec.cache_pos_axes(cfg),
         )
     return Model(
         cfg=cfg,
@@ -53,6 +56,7 @@ def build_model(cfg: ArchConfig) -> Model:
         decode=lambda p, tok, cache, n: lm.decode_fn(p, cfg, tok, cache, n),
         cache_spec=lambda b, s: lm.cache_spec(cfg, b, s),
         init_cache=lambda b, s: lm.init_cache(cfg, b, s),
+        cache_pos_axes=lambda: lm.cache_pos_axes(cfg),
     )
 
 

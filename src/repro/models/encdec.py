@@ -223,6 +223,12 @@ def cache_spec(cfg: ArchConfig, batch: int, max_len: int) -> dict:
     }
 
 
+def cache_pos_axes(cfg: ArchConfig) -> dict:
+    """Per `cache_spec` leaf, the axis that holds one entry per decoded
+    token; the cross-attention K/V span the encoder's frames instead."""
+    return {"k": 3, "v": 3, "cross_k": None, "cross_v": None}
+
+
 def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> dict:
     return jax.tree.map(lambda sd: jnp.zeros(sd.shape, sd.dtype),
                         cache_spec(cfg, batch, max_len))

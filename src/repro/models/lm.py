@@ -295,6 +295,19 @@ def cache_spec(cfg: ArchConfig, batch: int, max_len: int) -> dict:
     return spec
 
 
+def cache_pos_axes(cfg: ArchConfig) -> dict:
+    """Per `cache_spec` leaf, the axis that holds one entry per token, or
+    None for recurrent state.  `decode_fn` writes position ``cache_len``
+    there, so until a ring buffer wraps the entries at or past
+    ``cache_len`` are still `init_cache`'s zeros."""
+    out: dict = {}
+    for slot in range(period_length(cfg)):
+        mixer, _ = slot_kind(cfg, slot)
+        out[f"slot{slot}"] = ({"k": 3, "v": 3} if mixer == "attn"
+                              else {"conv": None, "ssm": None})
+    return out
+
+
 def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> dict:
     return jax.tree.map(lambda sd: jnp.zeros(sd.shape, sd.dtype),
                         cache_spec(cfg, batch, max_len))

@@ -101,6 +101,8 @@ class ServingEngine:
         self.params = params
         self.max_len = max_len
         self.kvstore = kvstore
+        # snapshots keep only the live positions of these axes
+        self.pos_axes = model.cache_pos_axes()
 
         def decode_step(params, tok, cache, cache_len):
             return model.decode(params, tok, cache, cache_len)
@@ -195,7 +197,8 @@ class ServingEngine:
                 cache_len = cache_len + 1
                 info["prefill_steps"] += 1
                 if (t + 1) in snap_lens and (t + 1) > start:
-                    if kv.put(prompt[:t + 1], cache) is not None:
+                    if kv.put(prompt[:t + 1], cache,
+                              pos_axes=self.pos_axes) is not None:
                         info["kv_stored"] += 1
         out: list[int] = []
         with span("engine.decode"):

@@ -20,9 +20,17 @@ mounted bucket directory)::
 ``key`` is a hash of the exact token prefix.  ``blocks.bin`` holds one
 segment per (cache leaf, layer period) — the layerwise block granularity
 ObjectCache fetches — and the manifest records each segment's
-(offset, nbytes, dtype, shape), so a reader can fetch any single layer
-with one ranged read (`ObjcacheFS.read_file_range`).  The manifest is
-committed with the same write-then-rename discipline as
+(offset, nbytes, dtype, shape, full_shape), so a reader can fetch any
+single layer with one ranged read (`ObjcacheFS.read_file_range`).
+``shape`` is what the segment holds and ``full_shape`` the writer's
+allocated per-period shape; they differ only along a leaf's position axis
+(the kv axis of attention K/V), where a snapshot keeps its first
+``cache_len`` positions: the rest are the zeros `init_cache` wrote, so
+they are never moved.  Recurrent state (SSM ``conv``/``ssm``) has no
+position axis, and a ring buffer that has wrapped (``cache_len`` at or
+past its extent) has no dead positions: both are stored whole.  A
+manifest without ``full_shape`` (older writers) holds whole segments.
+The manifest is committed with the same write-then-rename discipline as
 `checkpoint/manager.py`: rename is a 2PC transaction, so a prefix either
 has a complete manifest or is invisible — a crashed writer never publishes
 a partial cache.
@@ -34,8 +42,9 @@ fact.  The store therefore saves snapshots only at agreed lengths
 (`snapshot_lens`: every ``block_tokens``-th position plus ``prompt_len-1``,
 the state that emits the first token), and `lookup` probes exactly those
 lengths, longest first.  Restoring a snapshot is bit-exact: segments are
-raw array bytes, and a zero-padded tail along the kv axis is invisible to
-`attention_decode`'s ``cache_len`` mask (and to an unwrapped ring buffer).
+raw array bytes placed into a zeroed array of the target's shape, and a
+zero tail along the kv axis is invisible to `attention_decode`'s
+``cache_len`` mask (and to an unwrapped ring buffer).
 
 This module is numpy-only on purpose: the benchmark gate
 (`benchmarks/kv_smoke.py`) exercises the data path without importing JAX.
@@ -90,32 +99,31 @@ def _unflatten(items: dict[str, np.ndarray]) -> dict:
     return out
 
 
-def _fit(arr: np.ndarray, target_shape: tuple[int, ...], cache_len: int,
-         leaf: str) -> np.ndarray:
-    """Adapt a restored (n_periods, ...) leaf to `target_shape`.  At most
-    one axis may differ (the kv/time axis, when the reader's ``max_len``
-    differs from the writer's); resizing it is exact only while the first
-    ``cache_len`` positions are the only live ones, i.e. the cache has not
-    wrapped past the smaller of the two sizes."""
-    if arr.shape == tuple(target_shape):
-        return arr
-    diff = [i for i, (a, b) in enumerate(zip(arr.shape, target_shape))
-            if a != b]
-    if len(arr.shape) != len(target_shape) or len(diff) != 1:
-        raise ValueError(f"kv block {leaf!r}: stored shape {arr.shape} "
-                         f"incompatible with target {tuple(target_shape)}")
-    ax = diff[0]
-    lo = min(arr.shape[ax], target_shape[ax])
-    if cache_len > lo:
-        raise ValueError(
-            f"kv block {leaf!r}: cannot resize axis {ax} from "
-            f"{arr.shape[ax]} to {target_shape[ax]} with cache_len="
-            f"{cache_len} live positions (cache wrapped)")
-    out = np.zeros(target_shape, arr.dtype)
-    sel = [slice(None)] * arr.ndim
-    sel[ax] = slice(0, lo)
-    out[tuple(sel)] = arr[tuple(sel)]
-    return out
+def _live_index(stored: tuple[int, ...], full: tuple[int, ...],
+                target: tuple[int, ...], cache_len: int,
+                leaf: str) -> tuple[slice, ...]:
+    """Where a stored per-period segment goes in a `target`-shaped one.
+
+    `stored`, the writer's `full` shape and `target` may disagree along one
+    axis only: the position axis, cut to ``cache_len`` by the writer and
+    sized to the reader's ``max_len``.  Resizing it is exact only while the
+    first ``cache_len`` positions are the only live ones, i.e. the cache
+    has not wrapped past the smaller of the two sizes."""
+    diff = [i for i, (s, f, t) in enumerate(zip(stored, full, target))
+            if not s == f == t]
+    if not len(stored) == len(full) == len(target) or len(diff) > 1:
+        raise ValueError(f"kv block {leaf!r}: stored shape {full} "
+                         f"incompatible with target {target}")
+    sel = [slice(None)] * len(target)
+    if diff:
+        ax = diff[0]
+        if full[ax] != target[ax] and cache_len > min(full[ax], target[ax]):
+            raise ValueError(
+                f"kv block {leaf!r}: cannot resize axis {ax} from "
+                f"{full[ax]} to {target[ax]} with cache_len="
+                f"{cache_len} live positions (cache wrapped)")
+        sel[ax] = slice(0, min(stored[ax], target[ax]))
+    return tuple(sel)
 
 
 class KVCacheStore:
@@ -133,10 +141,11 @@ class KVCacheStore:
         self.fs = fs
         self.root = root.rstrip("/")
         self.block_tokens = block_tokens
-        # counters surfaced by benchmarks: puts/put_bytes on the write side,
+        # counters surfaced by benchmarks: puts/put_bytes on the write side
+        # (trimmed_bytes: dead positions past cache_len a put left out),
         # hits/misses/probes on the lookup side, get_bytes on the read side
         self.stats: dict[str, int] = {
-            "puts": 0, "put_bytes": 0, "dup_puts": 0,
+            "puts": 0, "put_bytes": 0, "trimmed_bytes": 0, "dup_puts": 0,
             "hits": 0, "misses": 0, "probes": 0, "gets": 0, "get_bytes": 0,
         }
 
@@ -172,15 +181,24 @@ class KVCacheStore:
     def has(self, tokens) -> bool:
         return self.fs.exists(f"{self._dir(prefix_key(tokens))}/{_MANIFEST}")
 
-    def put(self, tokens, cache: dict, batch_index: int = 0) -> dict | None:
+    def put(self, tokens, cache: dict, batch_index: int = 0,
+            pos_axes: dict | None = None) -> dict | None:
         """Persist one batch row of `cache` keyed by the exact `tokens`
         prefix.  Returns the manifest, or None if this prefix is already
-        stored (first writer wins; blocks are immutable once published)."""
+        stored (first writer wins; blocks are immutable once published).
+
+        `pos_axes` (a tree like `cache`, e.g. `Model.cache_pos_axes()`)
+        gives each leaf's position axis in the ``(n_periods, batch, ...)``
+        layout, or None for state without one.  A leaf with a position
+        axis longer than ``cache_len = len(tokens)`` is stored as its first
+        ``cache_len`` positions; every other leaf, and every leaf when
+        `pos_axes` is None, is stored whole."""
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if tokens.size == 0:
             return None
         key = prefix_key(tokens)
         d = self._dir(key)
+        axes = dict(_flat_items(pos_axes)) if pos_axes is not None else {}
         with span("kv.put"):
             with span("kv.put.fs"):
                 stored = self.fs.exists(f"{d}/{_MANIFEST}")
@@ -192,22 +210,31 @@ class KVCacheStore:
                         for leaf, arr in _flat_items(cache)]
             with span("kv.put.pack"):
                 segs: list[dict] = []
-                blobs: list[bytes] = []
-                off = 0
+                blobs: list[np.ndarray] = []
+                off = trimmed = 0
                 for leaf, arr in host:
                     if arr.ndim < 2 or batch_index >= arr.shape[1]:
                         raise ValueError(
                             f"cache leaf {leaf!r}: expected "
                             f"(n_periods, batch, ...), got {arr.shape}")
-                    row = np.ascontiguousarray(arr[:, batch_index])
-                    for p in range(row.shape[0]):  # one block per layer period
-                        raw = np.ascontiguousarray(row[p]).tobytes()
+                    row = arr[:, batch_index]
+                    full, whole = row.shape[1:], row.nbytes
+                    ax = axes.get(leaf)
+                    if ax is not None and tokens.size < arr.shape[ax]:
+                        # ax counts the batch axis, which `row` has dropped
+                        row = row[(slice(None),) * (ax - 1)
+                                  + (slice(0, tokens.size),)]
+                    # one copy of the live positions, all periods in order
+                    row = np.ascontiguousarray(row)
+                    trimmed += whole - row.nbytes
+                    for p in range(row.shape[0]):  # one block per period
                         segs.append({"leaf": leaf, "period": p,
-                                     "offset": off, "nbytes": len(raw),
+                                     "offset": off, "nbytes": row[p].nbytes,
                                      "dtype": str(arr.dtype),
-                                     "shape": list(row.shape[1:])})
-                        blobs.append(raw)
-                        off += len(raw)
+                                     "shape": list(row.shape[1:]),
+                                     "full_shape": list(full)})
+                        off += row[p].nbytes
+                    blobs.append(row.reshape(-1).view(np.uint8))
                 blocks = b"".join(blobs)
             with span("kv.put.fs"):
                 self.fs.makedirs(d)
@@ -220,6 +247,7 @@ class KVCacheStore:
                 self.fs.rename(tmp, f"{d}/{_MANIFEST}")   # 2PC publish point
         self.stats["puts"] += 1
         self.stats["put_bytes"] += off
+        self.stats["trimmed_bytes"] += trimmed
         return manifest
 
     def lookup(self, tokens, cap: int | None = None
@@ -248,11 +276,14 @@ class KVCacheStore:
         """Fetch stored blocks for `key` as a batch-1 cache (nested dict of
         numpy arrays shaped ``(n_periods, 1, ...)``).
 
-        `like` (a cache pytree of the same structure, e.g. the engine's
-        freshly initialized cache) pins target shapes/dtypes: kv axes are
-        zero-padded or sliced to the reader's ``max_len`` where that is
-        exact (see `_fit`), and a dtype mismatch is an error, not a cast —
-        a silently converted cache would break bit-determinism.
+        `like` (a tree of the same structure whose leaves carry ``.shape``
+        and ``.dtype``, e.g. the engine's freshly initialized cache or its
+        `cache_spec`; no values are read) pins target shapes/dtypes: kv
+        axes are zero-padded or cut to the reader's ``max_len`` where that
+        is exact (see `_live_index`), and a dtype mismatch is an error, not
+        a cast — a silently converted cache would break bit-determinism.
+        Without `like`, each leaf comes back in the writer's full shape.
+        Either way, positions a put left out read as zeros.
 
         `layers` optionally restricts the fetch to a subset of leaf paths
         (e.g. ``{"slot0/k"}``); each selected segment is one ranged read,
@@ -265,7 +296,7 @@ class KVCacheStore:
                     if layers is None or s["leaf"] in layers]
             if layers is None:
                 # whole-prefix restore: one sequential read of the blocks file
-                raw_all = self.fs.read_file(path)
+                raw_all = memoryview(self.fs.read_file(path))
             else:
                 raws = [self.fs.read_file_range(path, s["offset"],
                                                 s["nbytes"]) for s in want]
@@ -273,31 +304,41 @@ class KVCacheStore:
             if layers is None:
                 raws = [raw_all[s["offset"]:s["offset"] + s["nbytes"]]
                         for s in want]
-            per_leaf: dict[str, dict[int, np.ndarray]] = {}
+            per_leaf: dict[str, list[tuple[dict, object]]] = {}
             for seg, raw in zip(want, raws):
                 if len(raw) != seg["nbytes"]:
                     raise ValueError(
                         f"kv block {seg['leaf']}[{seg['period']}] of {key}: "
                         f"read {len(raw)} bytes, manifest says "
                         f"{seg['nbytes']}")
-                arr = np.frombuffer(raw, dtype=seg["dtype"]).reshape(
-                    seg["shape"])
-                per_leaf.setdefault(seg["leaf"], {})[seg["period"]] = arr
+                per_leaf.setdefault(seg["leaf"], []).append((seg, raw))
                 self.stats["get_bytes"] += len(raw)
             self.stats["gets"] += 1
             like_flat = dict(_flat_items(like)) if like is not None else {}
             leaves: dict[str, np.ndarray] = {}
-            for leaf, periods in per_leaf.items():
-                stacked = np.stack([periods[p] for p in sorted(periods)])
+            for leaf, parts in per_leaf.items():
+                seg0 = parts[0][0]
+                dtype = np.dtype(seg0["dtype"])
+                full = tuple(seg0.get("full_shape", seg0["shape"]))
+                shape = (len(parts),) + full
                 tgt = like_flat.get(leaf)
                 if tgt is not None:
-                    tgt = np.asarray(tgt)
-                    if str(tgt.dtype) != str(stacked.dtype):
+                    if str(tgt.dtype) != str(dtype):
                         raise ValueError(
                             f"kv block {leaf!r}: stored dtype "
-                            f"{stacked.dtype} != cache dtype {tgt.dtype}")
+                            f"{dtype} != cache dtype {tgt.dtype}")
                     # target per-row shape: drop the batch axis
-                    row_shape = (tgt.shape[0],) + tuple(tgt.shape[2:])
-                    stacked = _fit(stacked, row_shape, man["cache_len"], leaf)
-                leaves[leaf] = stacked[:, None]        # re-insert batch axis
+                    shape = (tgt.shape[0],) + tuple(tgt.shape[2:])
+                    if shape[0] != len(parts):
+                        raise ValueError(
+                            f"kv block {leaf!r}: {len(parts)} stored "
+                            f"periods, cache has {shape[0]}")
+                stored = tuple(seg0["shape"])
+                sel = _live_index(stored, full, shape[1:], man["cache_len"],
+                                  leaf)
+                out = np.zeros((shape[0], 1) + shape[1:], dtype)
+                for seg, raw in parts:
+                    src = np.frombuffer(raw, dtype=dtype).reshape(stored)
+                    out[seg["period"], 0][sel] = src[sel]
+                leaves[leaf] = out
             return _unflatten(leaves), man

@@ -34,6 +34,26 @@ def _synthetic_cache(nper=2, batch=2, kv_len=32, seed=0):
     }
 
 
+# position axis of each `_synthetic_cache` leaf, as `Model.cache_pos_axes`
+_POS_AXES = {"slot0": {"k": 3, "v": 3},
+             "slot1": {"conv": None, "ssm": None}}
+
+
+def _live_cache(cache_len, **kw):
+    """`_synthetic_cache` as a decode step leaves it: K/V zero at and past
+    `cache_len`, state dense."""
+    cache = _synthetic_cache(**kw)
+    for leaf in ("k", "v"):
+        cache["slot0"][leaf][:, :, :, cache_len:] = 0
+    return cache
+
+
+def _same_bytes(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.shape == b.shape and a.dtype == b.dtype
+    assert a.tobytes() == b.tobytes()
+
+
 def test_prefix_key_dtype_stable():
     toks = [5, 1, 400, 7]
     assert prefix_key(toks) == prefix_key(np.asarray(toks, np.int64))
@@ -149,6 +169,134 @@ def test_manifest_published_atomically(workdir):
     cl.close()
 
 
+def test_put_with_pos_axes_stores_live_positions(workdir):
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    cache = _live_cache(12, kv_len=32)
+    man = kv.put(np.arange(12, dtype=np.int32), cache, batch_index=1,
+                 pos_axes=_POS_AXES)
+    row = {f"{a}/{b}": x[:, 1] for a, leaves in cache.items()
+           for b, x in leaves.items()}
+    dead = 2 * row["slot0/k"][:, :, 12:].nbytes           # K and V tails
+    live = sum(a.nbytes for a in row.values()) - dead
+    assert man["nbytes"] == kv.stats["put_bytes"] == live
+    assert kv.stats["trimmed_bytes"] == dead
+    for seg in man["layers"]:
+        want = list(row[seg["leaf"]].shape[1:])
+        assert seg["full_shape"] == want
+        if seg["leaf"].startswith("slot0/"):
+            want[1] = 12                  # (heads, positions, head_dim)
+        assert seg["shape"] == want
+    assert len(fs.read_file(f"/b/kv/{man['key']}/blocks.bin")) == live
+    cl.close()
+
+
+@pytest.mark.parametrize("with_like", [True, False])
+def test_trimmed_get_equals_full_source(workdir, with_like):
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    cache = _live_cache(12, kv_len=32)
+    man = kv.put(np.arange(12, dtype=np.int32), cache, batch_index=1,
+                 pos_axes=_POS_AXES)
+    assert kv.stats["trimmed_bytes"] > 0
+    got, _ = kv.get(man["key"], like=cache if with_like else None)
+    for a, b in (("slot0", "k"), ("slot0", "v"), ("slot1", "conv"),
+                 ("slot1", "ssm")):
+        _same_bytes(got[a][b], cache[a][b][:, 1:2])    # zero tail included
+    assert kv.stats["get_bytes"] == man["nbytes"]
+    cl.close()
+
+
+@pytest.mark.parametrize("cache_len", [32, 40])
+def test_state_and_wrapped_ring_stored_whole(workdir, cache_len):
+    """SSM leaves have no position axis; a ring whose cache_len reached its
+    extent (32) has no dead positions: nothing is trimmed."""
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    cache = _synthetic_cache(kv_len=32)
+    man = kv.put(np.arange(cache_len, dtype=np.int32), cache,
+                 pos_axes=_POS_AXES)
+    assert kv.stats["trimmed_bytes"] == 0
+    assert man["nbytes"] == sum(cache[a][b][:, 0].nbytes for a, b in
+                                (("slot0", "k"), ("slot0", "v"),
+                                 ("slot1", "conv"), ("slot1", "ssm")))
+    assert all(seg["shape"] == seg["full_shape"] for seg in man["layers"])
+    got, _ = kv.get(man["key"], like=cache)
+    _same_bytes(got["slot0"]["k"], cache["slot0"]["k"][:, :1])
+    with pytest.raises(ValueError, match="resize"):   # wrapped: no resize
+        kv.get(man["key"], like=_synthetic_cache(kv_len=24))
+    cl.close()
+
+
+def test_snapshot_without_axes_still_restores(workdir):
+    """Whole snapshots — written without `pos_axes`, or by a writer whose
+    manifest has no ``full_shape`` — restore as before."""
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    cache = _live_cache(12, kv_len=32)
+    man = kv.put(np.arange(12, dtype=np.int32), cache)
+    assert kv.stats["trimmed_bytes"] == 0
+    path = f"/b/kv/{man['key']}/manifest.json"
+    old = json.loads(fs.read_file(path))
+    for seg in old["layers"]:
+        del seg["full_shape"]
+    fs.write_file(path, json.dumps(old).encode())
+    for like in (cache, None, _live_cache(12, kv_len=48, seed=1)):
+        got, _ = kv.get(man["key"], like=like)
+        kv_len = 32 if like is None else like["slot0"]["k"].shape[3]
+        assert got["slot0"]["k"].shape[3] == kv_len
+        _same_bytes(got["slot0"]["k"][:, :, :, :32],
+                    cache["slot0"]["k"][:, :1])
+        assert not got["slot0"]["k"][:, :, :, 32:].any()
+        _same_bytes(got["slot1"]["ssm"], cache["slot1"]["ssm"][:, :1])
+    cl.close()
+
+
+def test_trimmed_layer_subset_ranged_reads(workdir):
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    cache = _live_cache(12, kv_len=32)
+    man = kv.put(np.arange(12, dtype=np.int32), cache, pos_axes=_POS_AXES)
+    got, _ = kv.get(man["key"], layers={"slot0/v"}, like=cache)
+    assert list(got) == ["slot0"] and list(got["slot0"]) == ["v"]
+    _same_bytes(got["slot0"]["v"], cache["slot0"]["v"][:, :1])
+    # only the live positions of that leaf were read
+    assert kv.stats["get_bytes"] == cache["slot0"]["v"][:, 0, :, :12].nbytes
+    cl.close()
+
+
+def test_get_reads_only_shape_and_dtype_of_like(workdir):
+    """`like` leaves that carry nothing but .shape/.dtype pin the target:
+    no value of the reader's cache is read."""
+    import types
+
+    import jax
+
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    cache = _live_cache(12, kv_len=32)
+    man = kv.put(np.arange(12, dtype=np.int32), cache, pos_axes=_POS_AXES)
+    bigger = _synthetic_cache(kv_len=48)
+    for kind in (jax.ShapeDtypeStruct,
+                 lambda shape, dtype: types.SimpleNamespace(shape=shape,
+                                                            dtype=dtype)):
+        like = {a: {b: kind(x.shape, x.dtype) for b, x in leaves.items()}
+                for a, leaves in bigger.items()}
+        got, _ = kv.get(man["key"], like=like)
+        k = got["slot0"]["k"]
+        assert k.shape == (2, 1, 2, 48, 8)
+        _same_bytes(k[:, :, :, :32], cache["slot0"]["k"][:, :1])
+        assert not k[:, :, :, 32:].any()
+        _same_bytes(got["slot1"]["conv"], cache["slot1"]["conv"][:, :1])
+    cl.close()
+
+
 def test_read_file_range(fs):
     data = bytes(range(256)) * 2048            # 512 KiB, 2 chunks
     fs.write_file("/b/rng.bin", data)
@@ -177,11 +325,40 @@ def _engine(arch, fs, kv_root=None, max_len=64, block_tokens=8):
     return ServingEngine(model, params, max_len=max_len, kvstore=kv), cfg
 
 
-@pytest.mark.parametrize("arch", ["qwen3-0.6b", "mamba2-370m"])
+def _record_snapshots(kv):
+    """Wrap `kv.put`/`kv.get` to keep, per key, the device cache each put
+    saw (as numpy) and each cache a get restored."""
+    import jax
+
+    put_saw, got = {}, []
+    real_put, real_get = kv.put, kv.get
+
+    def put(tokens, cache, **kw):
+        man = real_put(tokens, cache, **kw)
+        if man is not None:
+            put_saw[man["key"]] = jax.tree.map(np.asarray, cache)
+        return man
+
+    def get(key, **kw):
+        out = real_get(key, **kw)
+        got.append((key, out[0]))
+        return out
+
+    kv.put, kv.get = put, get
+    return put_saw, got
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "mamba2-370m",
+                                  "h2o-danube-3-4b", "jamba-v0.1-52b"])
 def test_reuse_tokens_identical(workdir, arch):
+    """Dense attention, SSM state, a sliding-window ring (window 32 at the
+    reduced size, wrapped by the 40-token prompt) and a hybrid."""
+    import jax
+
     cl = make_cluster(workdir)
     fs = make_fs(cl, consistency="weak")
     engine, cfg = _engine(arch, fs, kv_root="/b/kv")
+    put_saw, got = _record_snapshots(engine.kvstore)
     rng = np.random.default_rng(3)
     prompt = rng.integers(0, cfg.vocab, size=21, dtype=np.int32)
 
@@ -199,9 +376,30 @@ def test_reuse_tokens_identical(workdir, arch):
     longer = np.concatenate([prompt,
                              rng.integers(0, cfg.vocab, 9, dtype=np.int32)])
     ref = engine.generate([longer], max_new=6)[0]
-    got, i_long = engine.generate_with_reuse(longer, max_new=6)
-    assert got == ref
+    got_toks, i_long = engine.generate_with_reuse(longer, max_new=6)
+    assert got_toks == ref
     assert i_long["reused_len"] >= 16        # ≥ the highest shared block
+
+    # past the ring's window: the 39-token snapshot is a wrapped ring
+    longest = np.concatenate([longer,
+                              rng.integers(0, cfg.vocab, 10, dtype=np.int32)])
+    ref = engine.generate([longest], max_new=6)[0]
+    for _ in range(2):
+        out, _ = engine.generate_with_reuse(longest, max_new=6)
+        assert out == ref
+
+    # every restore, and every stored snapshot read back, is byte for byte
+    # the cache its put saw, zero tail included
+    kv = engine.kvstore
+    spec = engine.model.cache_spec(1, engine.max_len)
+    assert len(got) == 4
+    restored = got + [(k, kv.get(k, like=spec)[0]) for k in put_saw]
+    for key, tree in restored:
+        for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(put_saw[key])):
+            _same_bytes(a, b)
+    has_positions = any(ax is not None for ax in
+                        jax.tree.leaves(engine.model.cache_pos_axes()))
+    assert (kv.stats["trimmed_bytes"] > 0) == has_positions
     cl.close()
 
 
