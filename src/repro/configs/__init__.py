@@ -1,4 +1,4 @@
-"""Architecture registry: --arch <id> resolution for the 10 assigned
+"""Architecture registry: --arch <id> resolution for the 11
 architectures (exact configs from public literature; see each module's
 docstring for the source tier)."""
 
@@ -19,6 +19,7 @@ _MODULES = {
     "internvl2-2b": "internvl2_2b",
     "whisper-tiny": "whisper_tiny",
     "mamba2-370m": "mamba2_370m",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 ARCH_IDS = tuple(_MODULES)
@@ -41,7 +42,7 @@ def get_reduced(arch: str) -> ArchConfig:
 
 def cells(include_skipped: bool = False
           ) -> list[tuple[str, ShapeConfig, bool]]:
-    """All 40 (arch, shape) cells with a runnable flag.  long_500k is
+    """Every (arch, shape) cell with a runnable flag.  long_500k is
     skipped for pure full-attention archs (sub-quadratic requirement,
     DESIGN.md §Arch-applicability)."""
     out = []

@@ -19,11 +19,23 @@ class MoEConfig:
     n_experts: int
     top_k: int
     d_ff_expert: int
-    n_shared: int = 0            # shared (always-on) experts, qwen2-moe
+    d_ff_shared: int = 0         # width of the always-on shared SwiGLU; 0: none
     every: int = 1               # MoE replaces the MLP every `every` layers
     capacity_factor: float = 1.25
     router_z_coef: float = 1e-3  # router z-loss (stability)
     aux_coef: float = 1e-2       # load-balancing auxiliary loss
+    # expert parallelism: the layer holds rank `expert_rank`'s contiguous
+    # share of the `n_experts` the router scores; 1 rank holds them all
+    expert_ranks: int = 1
+    expert_rank: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts // self.expert_ranks
+
+    @property
+    def first_held(self) -> int:
+        return self.expert_rank * self.n_held
 
 
 @dataclass(frozen=True)
@@ -50,8 +62,15 @@ class ArchConfig:
     qkv_bias: bool = False       # qwen2/2.5
     window: int | None = None    # sliding-window attention (danube)
     rope_theta: float = 1e4
+    rope: bool = True            # False: no position embedding (NoPE)
+    attn_scale: float | None = None   # score scale; default head_dim**-0.5
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
+    # scalar multipliers (granite): the embedding's output, every sublayer's
+    # output before it joins the residual, and the logits (divided)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     moe: MoEConfig | None = None
     ssm: SSMConfig | None = None
     # hybrid (jamba): attention layer when (i % attn_period) == attn_offset,
@@ -119,8 +138,8 @@ class ArchConfig:
                 continue  # mamba2 has no separate MLP
             if self.is_moe_layer(i):
                 m = self.moe
-                total += d * m.n_experts \
-                    + 3 * d * m.d_ff_expert * (m.n_experts + m.n_shared)
+                total += d * m.n_experts + 3 * d * (
+                    m.d_ff_expert * m.n_held + m.d_ff_shared)
             else:
                 total += mlp_dense
             total += d  # ffn norm
@@ -133,16 +152,17 @@ class ArchConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top_k + shared experts only)."""
+        """Parameters touched per token (MoE: top_k + shared experts only;
+        of a share of the experts, the expected top_k * held / n_experts)."""
         if self.moe is None:
             return self.param_count()
         m = self.moe
         full = self.param_count()
         n_moe_layers = sum(1 for i in range(self.n_layers)
                            if self.is_moe_layer(i))
-        inactive = (m.n_experts - m.top_k) * 3 * self.d_model \
-            * m.d_ff_expert * n_moe_layers
-        return full - inactive
+        inactive = (m.n_held - m.top_k * m.n_held / m.n_experts) * 3 \
+            * self.d_model * m.d_ff_expert * n_moe_layers
+        return round(full - inactive)
 
 
 @dataclass(frozen=True)

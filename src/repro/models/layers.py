@@ -107,7 +107,8 @@ def _head_rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
 def attention_qkv(params: dict, x: jax.Array, cfg: ArchConfig,
                   positions: jax.Array) -> tuple[jax.Array, jax.Array,
                                                  jax.Array]:
-    """x (B, S, D) -> q (B, H, S, hd), k/v (B, Hkv, S, hd), roped."""
+    """x (B, S, D) -> q (B, H, S, hd), k/v (B, Hkv, S, hd), roped unless
+    the config has no position embedding."""
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim
     q = (x @ params["wq"])
@@ -123,9 +124,14 @@ def attention_qkv(params: dict, x: jax.Array, cfg: ArchConfig,
     if cfg.qk_norm:
         q = _head_rms(q, params["q_norm"], cfg.norm_eps)
         k = _head_rms(k, params["k_norm"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def score_scale(cfg: ArchConfig) -> float:
+    return cfg.attn_scale or cfg.resolved_head_dim ** -0.5
 
 
 def attention_out(params: dict, o: jax.Array) -> jax.Array:
@@ -138,7 +144,7 @@ def attention(params: dict, x: jax.Array, cfg: ArchConfig,
               positions: jax.Array, *, causal: bool = True) -> jax.Array:
     q, k, v = attention_qkv(params, x, cfg, positions)
     o = kops.flash_attention(q, k, v, causal=causal, window=cfg.window,
-                             impl=cfg.attn_impl)
+                             scale=cfg.attn_scale, impl=cfg.attn_impl)
     return attention_out(params, o)
 
 
@@ -163,7 +169,7 @@ def attention_decode(params: dict, x: jax.Array, cfg: ArchConfig,
     b, _, sq, hd = q.shape
     qg = q.reshape(b, cfg.n_kv_heads, g * sq, hd)
     s = jnp.einsum("bhqd,bhkd->bhqk", qg, ck,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
+                   preferred_element_type=jnp.float32) * score_scale(cfg)
     idx = jnp.arange(skv)
     mask = idx[None, None, None, :] <= cache_len
     if cfg.window is not None:
@@ -200,7 +206,7 @@ def attention_decode_ring(params: dict, x: jax.Array, cfg: ArchConfig,
     bsz, _, sq, hd = q.shape
     qg = q.reshape(bsz, cfg.n_kv_heads, g * sq, hd)
     s = jnp.einsum("bhqd,bhkd->bhqk", qg, ck,
-                   preferred_element_type=jnp.float32) * (hd ** -0.5)
+                   preferred_element_type=jnp.float32) * score_scale(cfg)
     s = jnp.where(mask[None, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(ck.dtype), cv,

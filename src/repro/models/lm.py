@@ -135,15 +135,23 @@ def init_params(key, cfg: ArchConfig) -> tuple[dict, dict]:
 # =========================================================================
 # forward
 # =========================================================================
+def _residual(cfg: ArchConfig, y: jax.Array) -> jax.Array:
+    """A sublayer's output as it joins the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return y
+
+
 def _apply_slot(sp: dict, x: jax.Array, cfg: ArchConfig, slot: int,
                 positions: jax.Array) -> tuple[jax.Array, jax.Array]:
     mixer, ffn = slot_kind(cfg, slot)
     aux = jnp.zeros((), jnp.float32)
     h = rmsnorm(sp["mixer_norm"], x, cfg.norm_eps)
     if mixer == "attn":
-        x = x + attention(sp["attn"], h, cfg, positions).astype(x.dtype)
+        y = attention(sp["attn"], h, cfg, positions)
     else:
-        x = x + mamba2_apply(sp["ssm"], h, cfg).astype(x.dtype)
+        y = mamba2_apply(sp["ssm"], h, cfg)
+    x = x + _residual(cfg, y).astype(x.dtype)
     # pin the residual's batch sharding at every slot: in heterogeneous
     # periods (jamba) GSPMD otherwise replicates the stream mid-period and
     # the MoE scatters blow up to global-batch all-reduces (§Perf#9)
@@ -152,9 +160,9 @@ def _apply_slot(sp: dict, x: jax.Array, cfg: ArchConfig, slot: int,
         h = rmsnorm(sp["ffn_norm"], x, cfg.norm_eps)
         if ffn == "moe":
             y, aux = moe_apply(sp["moe"], h, cfg)
-            x = x + y.astype(x.dtype)
         else:
-            x = x + mlp(sp["mlp"], h).astype(x.dtype)
+            y = mlp(sp["mlp"], h)
+        x = x + _residual(cfg, y).astype(x.dtype)
         x = ctx.shard_batch(x)
     return x, aux
 
@@ -197,26 +205,40 @@ def backbone(params: dict, x: jax.Array, cfg: ArchConfig,
     return rmsnorm(params["final_norm"], x, cfg.norm_eps), aux
 
 
+def embed_tokens(params: dict, cfg: ArchConfig, tokens: jax.Array
+                 ) -> jax.Array:
+    x = embed(params["embed"], tokens)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
 def embed_inputs(params: dict, cfg: ArchConfig, tokens: jax.Array,
                  frontend: jax.Array | None) -> jax.Array:
-    x = embed(params["embed"], tokens)
+    x = embed_tokens(params, cfg, tokens)
     if cfg.frontend is not None and frontend is not None:
         fe = frontend.astype(x.dtype) @ params["frontend_proj"]
         x = jnp.concatenate([fe, x], axis=1)
     return ctx.shard_batch(x)
 
 
+def _scale_logits(cfg: ArchConfig, logits: jax.Array) -> jax.Array:
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
 def logits_fn(params: dict, cfg: ArchConfig, hidden: jax.Array) -> jax.Array:
     table = params["embed"] if cfg.tie_embeddings else params["head"]
-    return unembed(table, hidden)
+    return _scale_logits(cfg, unembed(table, hidden))
 
 
-def _ce_terms(table: jax.Array, hidden: jax.Array, labels: jax.Array
-              ) -> tuple[jax.Array, jax.Array, jax.Array]:
+def _ce_terms(cfg: ArchConfig, table: jax.Array, hidden: jax.Array,
+              labels: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Cross-entropy pieces for one sequence chunk (f32 logits live only
     within the chunk)."""
-    logits = jnp.einsum("bsd,vd->bsv", hidden.astype(jnp.float32),
-                        table.astype(jnp.float32))
+    logits = _scale_logits(cfg, jnp.einsum(
+        "bsd,vd->bsv", hidden.astype(jnp.float32), table.astype(jnp.float32)))
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(
         logits, jnp.maximum(labels, 0)[..., None], axis=-1)[..., 0]
@@ -245,7 +267,7 @@ def loss_fn(params: dict, cfg: ArchConfig, batch: dict,
         else params["head"]["table"]
     s = hidden.shape[1]
     if s % loss_chunk or s <= loss_chunk:
-        nll, z2, n = _ce_terms(table, hidden, labels)
+        nll, z2, n = _ce_terms(cfg, table, hidden, labels)
     else:
         nc = s // loss_chunk
         hc = hidden.reshape(hidden.shape[0], nc, loss_chunk, -1)
@@ -253,7 +275,7 @@ def loss_fn(params: dict, cfg: ArchConfig, batch: dict,
 
         def chunk_body(carry, inp):
             h, l = inp
-            t_nll, t_z2, t_n = _ce_terms(table, h, l)
+            t_nll, t_z2, t_n = _ce_terms(cfg, table, h, l)
             return (carry[0] + t_nll, carry[1] + t_z2, carry[2] + t_n), None
 
         (nll, z2, n), _ = jax.lax.scan(
@@ -327,8 +349,10 @@ def prefill_fn(params: dict, cfg: ArchConfig, batch: dict) -> jax.Array:
 
 def decode_fn(params: dict, cfg: ArchConfig, token: jax.Array, cache: dict,
               cache_len: jax.Array) -> tuple[jax.Array, dict]:
-    """One decode step: token (B, 1) -> (logits (B, 1, V), new cache)."""
-    x = embed(params["embed"], token)
+    """One decode step: token (B, 1) -> (logits (B, 1, V), new cache).
+    The three kinds of sublayer run under the scopes ``attn``, ``ssm`` and
+    ``moe``, which name their ops in a device trace."""
+    x = embed_tokens(params, cfg, token)
     plen = period_length(cfg)
 
     def period_body(x, inp):
@@ -344,22 +368,25 @@ def decode_fn(params: dict, cfg: ArchConfig, token: jax.Array, cache: dict,
                 use_ring = (cfg.window is not None
                             and cs["k"].shape[2] == cfg.window)
                 dec = attention_decode_ring if use_ring else attention_decode
-                o, nk, nv = dec(sp["attn"], h, cfg, cs["k"], cs["v"],
-                                cache_len)
-                x = x + o
+                with jax.named_scope("attn"):
+                    o, nk, nv = dec(sp["attn"], h, cfg, cs["k"], cs["v"],
+                                    cache_len)
+                x = x + _residual(cfg, o)
                 new_cc[f"slot{slot}"] = {"k": nk, "v": nv}
             else:
-                o, nconv, nssm = mamba2_decode(sp["ssm"], h, cfg,
-                                               cs["conv"], cs["ssm"])
-                x = x + o
+                with jax.named_scope("ssm"):
+                    o, nconv, nssm = mamba2_decode(sp["ssm"], h, cfg,
+                                                   cs["conv"], cs["ssm"])
+                x = x + _residual(cfg, o)
                 new_cc[f"slot{slot}"] = {"conv": nconv, "ssm": nssm}
             if ffn != "none":
                 h = rmsnorm(sp["ffn_norm"], x, cfg.norm_eps)
                 if ffn == "moe":
-                    y, _ = moe_apply(sp["moe"], h, cfg, dropless=True)
-                    x = x + y.astype(x.dtype)
+                    with jax.named_scope("moe"):
+                        y, _ = moe_apply(sp["moe"], h, cfg, dropless=True)
                 else:
-                    x = x + mlp(sp["mlp"], h).astype(x.dtype)
+                    y = mlp(sp["mlp"], h)
+                x = x + _residual(cfg, y).astype(x.dtype)
         return x, new_cc
 
     x, new_cache = jax.lax.scan(period_body, x, (params["periods"], cache))

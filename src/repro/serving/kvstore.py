@@ -142,10 +142,13 @@ class KVCacheStore:
         self.root = root.rstrip("/")
         self.block_tokens = block_tokens
         # counters surfaced by benchmarks: puts/put_bytes on the write side
-        # (trimmed_bytes: dead positions past cache_len a put left out),
+        # (state_bytes: the part of put_bytes in leaves with no position
+        # axis, such as SSM state, the rest being position-axis K/V;
+        # trimmed_bytes: dead positions past cache_len a put left out),
         # hits/misses/probes on the lookup side, get_bytes on the read side
         self.stats: dict[str, int] = {
-            "puts": 0, "put_bytes": 0, "trimmed_bytes": 0, "dup_puts": 0,
+            "puts": 0, "put_bytes": 0, "state_bytes": 0, "trimmed_bytes": 0,
+            "dup_puts": 0,
             "hits": 0, "misses": 0, "probes": 0, "gets": 0, "get_bytes": 0,
         }
 
@@ -211,7 +214,7 @@ class KVCacheStore:
             with span("kv.put.pack"):
                 segs: list[dict] = []
                 blobs: list[np.ndarray] = []
-                off = trimmed = 0
+                off = trimmed = state = 0
                 for leaf, arr in host:
                     if arr.ndim < 2 or batch_index >= arr.shape[1]:
                         raise ValueError(
@@ -220,7 +223,9 @@ class KVCacheStore:
                     row = arr[:, batch_index]
                     full, whole = row.shape[1:], row.nbytes
                     ax = axes.get(leaf)
-                    if ax is not None and tokens.size < arr.shape[ax]:
+                    if ax is None:
+                        state += whole
+                    elif tokens.size < arr.shape[ax]:
                         # ax counts the batch axis, which `row` has dropped
                         row = row[(slice(None),) * (ax - 1)
                                   + (slice(0, tokens.size),)]
@@ -247,6 +252,7 @@ class KVCacheStore:
                 self.fs.rename(tmp, f"{d}/{_MANIFEST}")   # 2PC publish point
         self.stats["puts"] += 1
         self.stats["put_bytes"] += off
+        self.stats["state_bytes"] += state
         self.stats["trimmed_bytes"] += trimmed
         return manifest
 

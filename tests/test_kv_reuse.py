@@ -182,6 +182,8 @@ def test_put_with_pos_axes_stores_live_positions(workdir):
     live = sum(a.nbytes for a in row.values()) - dead
     assert man["nbytes"] == kv.stats["put_bytes"] == live
     assert kv.stats["trimmed_bytes"] == dead
+    assert kv.stats["state_bytes"] == row["slot1/conv"].nbytes \
+        + row["slot1/ssm"].nbytes
     for seg in man["layers"]:
         want = list(row[seg["leaf"]].shape[1:])
         assert seg["full_shape"] == want
@@ -220,6 +222,8 @@ def test_state_and_wrapped_ring_stored_whole(workdir, cache_len):
     man = kv.put(np.arange(cache_len, dtype=np.int32), cache,
                  pos_axes=_POS_AXES)
     assert kv.stats["trimmed_bytes"] == 0
+    assert kv.stats["state_bytes"] == cache["slot1"]["conv"][:, 0].nbytes \
+        + cache["slot1"]["ssm"][:, 0].nbytes    # the ring counts as K/V
     assert man["nbytes"] == sum(cache[a][b][:, 0].nbytes for a, b in
                                 (("slot0", "k"), ("slot0", "v"),
                                  ("slot1", "conv"), ("slot1", "ssm")))
@@ -349,10 +353,13 @@ def _record_snapshots(kv):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "mamba2-370m",
-                                  "h2o-danube-3-4b", "jamba-v0.1-52b"])
+                                  "h2o-danube-3-4b", "jamba-v0.1-52b",
+                                  "granite-4.0-h-small"])
 def test_reuse_tokens_identical(workdir, arch):
     """Dense attention, SSM state, a sliding-window ring (window 32 at the
-    reduced size, wrapped by the 40-token prompt) and a hybrid."""
+    reduced size, wrapped by the 40-token prompt) and two hybrids, the
+    second with NoPE attention and an MoE in every layer: its snapshots mix
+    trimmed K/V with whole SSM state."""
     import jax
 
     cl = make_cluster(workdir)
@@ -397,9 +404,13 @@ def test_reuse_tokens_identical(workdir, arch):
     for key, tree in restored:
         for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(put_saw[key])):
             _same_bytes(a, b)
-    has_positions = any(ax is not None for ax in
-                        jax.tree.leaves(engine.model.cache_pos_axes()))
+    axes = jax.tree.leaves(engine.model.cache_pos_axes(),
+                           is_leaf=lambda a: a is None)
+    has_positions = any(ax is not None for ax in axes)
     assert (kv.stats["trimmed_bytes"] > 0) == has_positions
+    # state_bytes is the part of put_bytes with no position axis
+    assert (kv.stats["state_bytes"] > 0) == (None in axes)
+    assert (kv.stats["state_bytes"] < kv.stats["put_bytes"]) == has_positions
     cl.close()
 
 

@@ -9,6 +9,7 @@ time may load the TPU library, and pytest-xdist workers import every test
 file.
 """
 
+import dataclasses
 import os
 
 import jax
@@ -97,6 +98,25 @@ def test_qwen3_decode_fits_one_chip(one_chip):
         params, _spec((batch, 1), jnp.int32, one_chip), cache,
         _spec((), jnp.int32, one_chip)).compile()
     assert 0 < _hbm_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
+
+
+def test_granite_expert_rank_decode_fits_one_chip(one_chip):
+    """granite-4.0-h-small as the benchmark cuts it: one 10-layer stage
+    (attention at slot 5), 9 of the 72 experts held, batch 1, 1024
+    positions: 4.8 GB of weights."""
+    cfg = get_config("granite-4.0-h-small")
+    cfg = cfg.with_(n_layers=10, moe=dataclasses.replace(
+        cfg.moe, expert_ranks=8, expert_rank=0))
+    model = build_model(cfg)
+    params = _place(jax.eval_shape(lambda k: model.init(k)[0],
+                                   jax.random.PRNGKey(0)), one_chip)
+    cache = _place(model.cache_spec(1, 1024), one_chip)
+    compiled = jax.jit(model.decode).lower(
+        params, _spec((1, 1), jnp.int32, one_chip), cache,
+        _spec((), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 4.8e9, mem      # the held weights
+    assert 0 < _hbm_bytes(compiled) < HBM_BYTES, mem
 
 
 def test_qwen3_train_step_fits_one_chip(one_chip):
