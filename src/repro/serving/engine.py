@@ -30,6 +30,7 @@ from ..checkpoint.manager import CheckpointManager
 from ..core.fs import ObjcacheFS
 from ..core.spans import span
 from ..models import Model
+from .kvstore import prefix_key
 
 
 class ModelStore:
@@ -108,6 +109,10 @@ class ServingEngine:
             return model.decode(params, tok, cache, cache_len)
         # a named function, so the trace names the module jit_decode_step
         self._decode = jax.jit(decode_step)
+        # a snapshot's block window, cut on the device at a traced start
+        # (`KVCacheStore.put`'s `cut`): one compile per leaf shape
+        self._cut = jax.jit(jax.lax.dynamic_slice_in_dim,
+                            static_argnums=(2, 3))
         self._prefill_tok = jax.jit(
             lambda p, b: model.prefill(p, b))
 
@@ -155,7 +160,10 @@ class ServingEngine:
         fresh cache, and prefills only the remaining tokens — partial-
         prefill resume.  While prefilling, snapshots are written back at
         the store's block boundaries (and at ``len(prompt) - 1``) so later
-        requests sharing the prefix start further along.  Decoding is
+        requests sharing the prefix start further along.  Each is a delta
+        over the snapshot restored or stored last: only its block window
+        leaves the device, and only the positions that base lacks are
+        written.  Decoding is
         identical to `generate` from there, so the emitted tokens are
         bit-identical with and without reuse (tier-1 asserts this).
 
@@ -168,6 +176,7 @@ class ServingEngine:
         assert 1 <= s <= self.max_len, (s, self.max_len)
         cache = self.model.init_cache(1, self.max_len)
         start = 0
+        base = None             # the snapshot the next put extends
         info = {"reused_len": 0, "prefill_steps": 0, "exact_hit": False,
                 "kv_read_bytes": 0, "kv_stored": 0}
         kv = self.kvstore
@@ -184,6 +193,7 @@ class ServingEngine:
                             cache, restored)
                 info.update(reused_len=start, exact_hit=(start == s - 1),
                             kv_read_bytes=man["nbytes"])
+                base = man
         cache_len = jnp.int32(start)
         toks = prompt[None, :]
         logits = None
@@ -197,9 +207,14 @@ class ServingEngine:
                 cache_len = cache_len + 1
                 info["prefill_steps"] += 1
                 if (t + 1) in snap_lens and (t + 1) > start:
-                    if kv.put(prompt[:t + 1], cache,
-                              pos_axes=self.pos_axes) is not None:
+                    man = kv.put(prompt[:t + 1], cache,
+                                 pos_axes=self.pos_axes, base=base,
+                                 cut=self._cut)
+                    if man is None:     # stored already: extend that one
+                        man = kv.manifest(prefix_key(prompt[:t + 1]))
+                    else:
                         info["kv_stored"] += 1
+                    base = man
         out: list[int] = []
         with span("engine.decode"):
             tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)

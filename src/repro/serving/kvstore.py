@@ -14,22 +14,45 @@ scale-to-zero drain — with no new protocol code.
 Layout per stored prefix (all paths under the store root, typically a
 mounted bucket directory)::
 
-    <root>/<key>/blocks.bin       # per-layer segments, concatenated
+    <root>/<key>/blocks.<id>.bin  # the bytes one put of it added
     <root>/<key>/manifest.json    # tokens, cache_len, segment table
 
-``key`` is a hash of the exact token prefix.  ``blocks.bin`` holds one
+``key`` is a hash of the exact token prefix.  The manifest holds one
 segment per (cache leaf, layer period) — the layerwise block granularity
-ObjectCache fetches — and the manifest records each segment's
-(offset, nbytes, dtype, shape, full_shape), so a reader can fetch any
-single layer with one ranged read (`ObjcacheFS.read_file_range`).
-``shape`` is what the segment holds and ``full_shape`` the writer's
-allocated per-period shape; they differ only along a leaf's position axis
-(the kv axis of attention K/V), where a snapshot keeps its first
-``cache_len`` positions: the rest are the zeros `init_cache` wrote, so
-they are never moved.  Recurrent state (SSM ``conv``/``ssm``) has no
-position axis, and a ring buffer that has wrapped (``cache_len`` at or
-past its extent) has no dead positions: both are stored whole.  A
-manifest without ``full_shape`` (older writers) holds whole segments.
+ObjectCache fetches — with its dtype, ``full_shape`` (the writer's
+allocated per-period shape), position ``axis`` and a list of pieces, each
+(``file``, ``offset``, ``nbytes``, ``shape``, ``pos``): raw array bytes
+at ``offset`` in some snapshot's blocks file (``file``, relative to
+the root), so a reader fetches any single layer with one ranged read per
+file (`ObjcacheFS.read_file_range`).  A piece with ``pos = [a, b]``
+holds positions ``a`` to ``b - 1`` along the leaf's position axis (the kv
+axis of attention K/V) as the first ``b - a`` positions of its stored
+``shape``.  A snapshot keeps only its first ``cache_len`` positions: the
+rest are the zeros `init_cache` wrote, so they are never moved.  A piece
+with ``pos`` null is the whole per-period array: recurrent state (SSM
+``conv``/``ssm``) has no position axis, and a ring buffer that has
+wrapped (``cache_len`` at or past its extent) has no dead positions, so
+both are stored whole, as is every leaf of a writer that names no
+position axes.
+
+Each put writes its blocks under a name of its own (``<id>`` is random),
+so no put ever rewrites a file that a published manifest may reference:
+two replicas storing the same prefix at once each write their own file,
+and whichever manifest the rename publishes matches its own bytes.
+
+A put may name a *base*: a stored snapshot whose tokens are a proper
+prefix of its own.  Its blocks file then holds only the positions
+``[base cache_len, cache_len)`` of each position-axis leaf, and its
+segments copy the base's pieces for the rest, so a reference is one
+level deep and a reader never follows a chain.  A manifest references
+only files whose manifests were committed before it.  Every stored byte
+still goes through the same durable write path: a delta only stops
+writing the same bytes twice.  Snapshots are immutable and this store
+deletes nothing; a future deleter must keep a blocks file while any
+committed manifest, or a manifest a put returned, references it.  Older
+manifests, whose segments are one (offset, nbytes, shape) in their own
+``<key>/blocks.bin``, with or without
+``full_shape``, still restore and may serve as a base.
 The manifest is committed with the same write-then-rename discipline as
 `checkpoint/manager.py`: rename is a 2PC transaction, so a prefix either
 has a complete manifest or is invisible — a crashed writer never publishes
@@ -57,13 +80,14 @@ from __future__ import annotations
 
 import hashlib
 import json
+import uuid
 
 import numpy as np
 
 from ..core.fs import ObjcacheFS
 from ..core.spans import span
 
-_BLOCKS = "blocks.bin"
+_BLOCKS = "blocks.bin"       # a snapshot's one file, before delta puts
 _MANIFEST = "manifest.json"
 
 
@@ -99,31 +123,93 @@ def _unflatten(items: dict[str, np.ndarray]) -> dict:
     return out
 
 
-def _live_index(stored: tuple[int, ...], full: tuple[int, ...],
-                target: tuple[int, ...], cache_len: int,
-                leaf: str) -> tuple[slice, ...]:
-    """Where a stored per-period segment goes in a `target`-shaped one.
+def _full_shape(seg: dict) -> tuple[int, ...]:
+    """The writer's allocated per-period shape of a segment (older writers
+    stored whole segments and recorded only ``shape``)."""
+    return tuple(seg["full_shape"] if "full_shape" in seg else seg["shape"])
 
-    `stored`, the writer's `full` shape and `target` may disagree along one
-    axis only: the position axis, cut to ``cache_len`` by the writer and
-    sized to the reader's ``max_len``.  Resizing it is exact only while the
-    first ``cache_len`` positions are the only live ones, i.e. the cache
-    has not wrapped past the smaller of the two sizes."""
-    diff = [i for i, (s, f, t) in enumerate(zip(stored, full, target))
-            if not s == f == t]
-    if not len(stored) == len(full) == len(target) or len(diff) > 1:
+
+def _pieces(seg: dict, key: str) -> tuple[int | None, list[dict]]:
+    """A segment's per-period position axis and its pieces.  A segment of
+    an older manifest (snapshot `key`'s) is one piece of its own blocks,
+    holding the first positions along the one axis where its ``shape`` is
+    shorter than its ``full_shape``, or the whole array where they agree."""
+    if "pieces" in seg:
+        return seg["axis"], seg["pieces"]
+    shape = seg["shape"]
+    diff = [i for i, (s, f) in enumerate(zip(shape, _full_shape(seg)))
+            if s != f]
+    axis = diff[0] if len(diff) == 1 else None
+    return axis, [{"file": f"{key}/{_BLOCKS}", "offset": seg["offset"],
+                   "nbytes": seg["nbytes"], "shape": shape,
+                   "pos": None if axis is None else [0, shape[axis]]}]
+
+
+def _base_refs(seg: dict | None, key: str, dtype: str,
+               full: tuple[int, ...], axis: int, since: int
+               ) -> list[dict] | None:
+    """The pieces of snapshot `key`'s segment `seg` that hold positions
+    ``[0, since)`` of a leaf laid out as `dtype`, per-period `full` shape
+    and position `axis`; None where the segment cannot serve."""
+    if seg is None or seg["dtype"] != dtype or _full_shape(seg) != full:
+        return None
+    seg_axis, pieces = _pieces(seg, key)
+    if len(pieces) == 1 and pieces[0]["pos"] is None:
+        # stored whole: its first `since` positions are the ones wanted
+        if tuple(pieces[0]["shape"]) != full:
+            return None
+        return [{**pieces[0], "pos": [0, since]}]
+    end = 0
+    for p in pieces:
+        if p["pos"] is None or p["pos"][0] != end:
+            return None
+        end = p["pos"][1]
+    return pieces if seg_axis == axis and end == since else None
+
+
+def _resize_axis(full: tuple[int, ...], target: tuple[int, ...],
+                 cache_len: int, leaf: str) -> int | None:
+    """The axis along which a stored per-period shape `full` and a
+    `target` one differ, if any.
+
+    They may disagree along one axis only: the position axis, sized to the
+    writer's and the reader's ``max_len``.  Resizing it is exact only while
+    the first ``cache_len`` positions are the only live ones, i.e. the
+    cache has not wrapped past the smaller of the two sizes."""
+    diff = [i for i, (f, t) in enumerate(zip(full, target)) if f != t]
+    if len(full) != len(target) or len(diff) > 1:
         raise ValueError(f"kv block {leaf!r}: stored shape {full} "
                          f"incompatible with target {target}")
-    sel = [slice(None)] * len(target)
-    if diff:
+    if diff and cache_len > min(full[diff[0]], target[diff[0]]):
         ax = diff[0]
-        if full[ax] != target[ax] and cache_len > min(full[ax], target[ax]):
-            raise ValueError(
-                f"kv block {leaf!r}: cannot resize axis {ax} from "
-                f"{full[ax]} to {target[ax]} with cache_len="
-                f"{cache_len} live positions (cache wrapped)")
-        sel[ax] = slice(0, min(stored[ax], target[ax]))
-    return tuple(sel)
+        raise ValueError(
+            f"kv block {leaf!r}: cannot resize axis {ax} from "
+            f"{full[ax]} to {target[ax]} with cache_len="
+            f"{cache_len} live positions (cache wrapped)")
+    return diff[0] if diff else None
+
+
+def _placement(piece: dict, axis: int | None, full: tuple[int, ...],
+               resize: int | None, target: tuple[int, ...], leaf: str
+               ) -> tuple[tuple[slice, ...], tuple[slice, ...]]:
+    """Where a stored piece goes in a `target`-shaped per-period array:
+    ``(source index, target index)``."""
+    src = [slice(None)] * len(target)
+    dst = list(src)
+    if piece["pos"] is None:
+        if tuple(piece["shape"]) != full:
+            raise ValueError(f"kv block {leaf!r}: stored shape "
+                             f"{piece['shape']} incompatible with {full}")
+        if resize is not None:
+            src[resize] = dst[resize] = slice(0, min(full[resize],
+                                                     target[resize]))
+    else:
+        if resize not in (None, axis):
+            raise ValueError(f"kv block {leaf!r}: stored shape {full} "
+                             f"incompatible with target {target}")
+        a, b = piece["pos"]
+        src[axis], dst[axis] = slice(0, b - a), slice(a, b)
+    return tuple(src), tuple(dst)
 
 
 class KVCacheStore:
@@ -144,11 +230,13 @@ class KVCacheStore:
         # counters surfaced by benchmarks: puts/put_bytes on the write side
         # (state_bytes: the part of put_bytes in leaves with no position
         # axis, such as SSM state, the rest being position-axis K/V;
-        # trimmed_bytes: dead positions past cache_len a put left out),
-        # hits/misses/probes on the lookup side, get_bytes on the read side
+        # shared_bytes: live positions a put referenced from its base
+        # instead of writing; trimmed_bytes: dead positions past cache_len
+        # a put left out), hits/misses/probes on the lookup side, get_bytes
+        # (bytes read) on the read side
         self.stats: dict[str, int] = {
-            "puts": 0, "put_bytes": 0, "state_bytes": 0, "trimmed_bytes": 0,
-            "dup_puts": 0,
+            "puts": 0, "put_bytes": 0, "state_bytes": 0, "shared_bytes": 0,
+            "trimmed_bytes": 0, "dup_puts": 0,
             "hits": 0, "misses": 0, "probes": 0, "gets": 0, "get_bytes": 0,
         }
 
@@ -185,7 +273,8 @@ class KVCacheStore:
         return self.fs.exists(f"{self._dir(prefix_key(tokens))}/{_MANIFEST}")
 
     def put(self, tokens, cache: dict, batch_index: int = 0,
-            pos_axes: dict | None = None) -> dict | None:
+            pos_axes: dict | None = None, base: dict | None = None,
+            cut=None) -> dict | None:
         """Persist one batch row of `cache` keyed by the exact `tokens`
         prefix.  Returns the manifest, or None if this prefix is already
         stored (first writer wins; blocks are immutable once published).
@@ -195,66 +284,137 @@ class KVCacheStore:
         layout, or None for state without one.  A leaf with a position
         axis longer than ``cache_len = len(tokens)`` is stored as its first
         ``cache_len`` positions; every other leaf, and every leaf when
-        `pos_axes` is None, is stored whole."""
+        `pos_axes` is None, is stored whole.
+
+        `base`, a manifest that `put` or `get` returned, makes the put a
+        delta: where the base's tokens are a proper prefix of `tokens`,
+        such a leaf writes only the positions past the base's
+        ``cache_len`` and references the base's pieces for the rest.  Any
+        other base is ignored and everything is written.
+
+        `cut`, called as ``cut(arr, start, size, axis)``, returns positions
+        ``[start, start + size)`` of a leaf along `axis`, e.g. as
+        `lax.dynamic_slice_in_dim` does on the device.  With it, a
+        position-axis leaf crosses to the host as the one
+        ``block_tokens``-position window that holds every position the put
+        writes, where one does; without it, as the whole leaf.  Snapshots
+        fall on every block boundary, so over its base a put's positions
+        lie in one window."""
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if tokens.size == 0:
             return None
+        n = int(tokens.size)
         key = prefix_key(tokens)
         d = self._dir(key)
         axes = dict(_flat_items(pos_axes)) if pos_axes is not None else {}
+        since, base_key, base_segs = self._base(base, tokens)
         with span("kv.put"):
             with span("kv.put.fs"):
                 stored = self.fs.exists(f"{d}/{_MANIFEST}")
             if stored:
                 self.stats["dup_puts"] += 1
                 return None
+            # per leaf: what to copy to the host, the positions [lo, n) it
+            # writes (part: False for a whole leaf) and its base's pieces
+            plan = []
+            for leaf, arr in _flat_items(cache):
+                shape = tuple(arr.shape)
+                if len(shape) < 2 or batch_index >= shape[1]:
+                    raise ValueError(
+                        f"cache leaf {leaf!r}: expected "
+                        f"(n_periods, batch, ...), got {shape}")
+                ax = axes.get(leaf)
+                item = {"leaf": leaf, "src": arr, "full": shape[2:],
+                        "ax": ax, "part": ax is not None and n < shape[ax],
+                        "lo": 0, "start": 0, "refs": [[]] * shape[0]}
+                if item["part"]:
+                    refs = [_base_refs(base_segs.get((leaf, p)), base_key,
+                                       str(arr.dtype), item["full"], ax - 2,
+                                       since) for p in range(shape[0])]
+                    if since and None not in refs:
+                        item.update(lo=since, refs=refs)
+                    bt = self.block_tokens
+                    size = min(bt, shape[ax])
+                    start = min(item["lo"] // bt * bt, shape[ax] - size)
+                    if cut is not None and n <= start + size:
+                        item.update(start=start,
+                                    src=cut(arr, start, size, ax))
+                plan.append(item)
             with span("kv.put.to_host"):
-                host = [(leaf, np.asarray(arr))
-                        for leaf, arr in _flat_items(cache)]
+                host = [np.asarray(item["src"]) for item in plan]
             with span("kv.put.pack"):
                 segs: list[dict] = []
                 blobs: list[np.ndarray] = []
-                off = trimmed = state = 0
-                for leaf, arr in host:
-                    if arr.ndim < 2 or batch_index >= arr.shape[1]:
-                        raise ValueError(
-                            f"cache leaf {leaf!r}: expected "
-                            f"(n_periods, batch, ...), got {arr.shape}")
+                off = shared = trimmed = state = 0
+                wid = uuid.uuid4().hex
+                blocks_file = f"{key}/blocks.{wid}.bin"
+                # position-axis pieces first: what a later delta references
+                # of this file is then one run of it
+                for item, arr in sorted(zip(plan, host),
+                                        key=lambda it: not it[0]["part"]):
+                    leaf, ax, lo = item["leaf"], item["ax"], item["lo"]
                     row = arr[:, batch_index]
-                    full, whole = row.shape[1:], row.nbytes
-                    ax = axes.get(leaf)
-                    if ax is None:
-                        state += whole
-                    elif tokens.size < arr.shape[ax]:
+                    whole = arr.dtype.itemsize * row.shape[0] \
+                        * int(np.prod(item["full"]))
+                    if item["part"]:
                         # ax counts the batch axis, which `row` has dropped
                         row = row[(slice(None),) * (ax - 1)
-                                  + (slice(0, tokens.size),)]
-                    # one copy of the live positions, all periods in order
+                                  + (slice(lo - item["start"],
+                                           n - item["start"]),)]
+                        live = whole // item["full"][ax - 2] * n
+                    else:
+                        live = whole
+                        if ax is None:
+                            state += whole
+                    # one copy of what is written, all periods in order
                     row = np.ascontiguousarray(row)
-                    trimmed += whole - row.nbytes
-                    for p in range(row.shape[0]):  # one block per period
+                    shared += live - row.nbytes
+                    trimmed += whole - live
+                    for p in range(row.shape[0]):  # one segment per period
+                        piece = {"file": blocks_file, "offset": off,
+                                 "nbytes": row[p].nbytes,
+                                 "shape": list(row.shape[1:]),
+                                 "pos": [lo, n] if item["part"] else None}
                         segs.append({"leaf": leaf, "period": p,
-                                     "offset": off, "nbytes": row[p].nbytes,
                                      "dtype": str(arr.dtype),
-                                     "shape": list(row.shape[1:]),
-                                     "full_shape": list(full)})
+                                     "full_shape": list(item["full"]),
+                                     "axis": None if ax is None else ax - 2,
+                                     "pieces": item["refs"][p] + [piece]})
                         off += row[p].nbytes
                     blobs.append(row.reshape(-1).view(np.uint8))
                 blocks = b"".join(blobs)
             with span("kv.put.fs"):
                 self.fs.makedirs(d)
-                self.fs.write_file(f"{d}/{_BLOCKS}", blocks)
+                self.fs.write_file(f"{self.root}/{blocks_file}", blocks)
                 manifest = {"key": key, "tokens": tokens.tolist(),
-                            "cache_len": int(tokens.size), "nbytes": off,
+                            "cache_len": n, "nbytes": off + shared,
+                            "blocks_nbytes": off,
                             "n_blocks": len(segs), "layers": segs}
-                tmp = f"{d}/.manifest.tmp"
+                tmp = f"{d}/.manifest.{wid}.tmp"
                 self.fs.write_file(tmp, json.dumps(manifest).encode())
                 self.fs.rename(tmp, f"{d}/{_MANIFEST}")   # 2PC publish point
         self.stats["puts"] += 1
         self.stats["put_bytes"] += off
+        self.stats["shared_bytes"] += shared
         self.stats["state_bytes"] += state
         self.stats["trimmed_bytes"] += trimmed
         return manifest
+
+    @staticmethod
+    def _base(base: dict | None, tokens: np.ndarray
+              ) -> tuple[int, str | None, dict]:
+        """``(cache_len, key, segments by (leaf, period))`` of a base
+        whose tokens are a proper prefix of `tokens`; ``(0, None, {})``
+        for any other."""
+        if base is None:
+            return 0, None, {}
+        prefix = np.asarray(base.get("tokens", ()), np.int32).reshape(-1)
+        m = prefix.size
+        if not (0 < m < tokens.size and base.get("cache_len") == m
+                and np.array_equal(prefix, tokens[:m])):
+            return 0, None, {}
+        return m, base["key"], {(s["leaf"], s["period"]): s
+                                for s in base["layers"]}
 
     def lookup(self, tokens, cap: int | None = None
                ) -> tuple[int, str] | None:
@@ -286,47 +446,44 @@ class KVCacheStore:
         and ``.dtype``, e.g. the engine's freshly initialized cache or its
         `cache_spec`; no values are read) pins target shapes/dtypes: kv
         axes are zero-padded or cut to the reader's ``max_len`` where that
-        is exact (see `_live_index`), and a dtype mismatch is an error, not
-        a cast — a silently converted cache would break bit-determinism.
-        Without `like`, each leaf comes back in the writer's full shape.
-        Either way, positions a put left out read as zeros.
+        is exact (see `_resize_axis`), and a dtype mismatch is an error,
+        not a cast — a silently converted cache would break
+        bit-determinism.  Without `like`, each leaf comes back in the
+        writer's full shape.  Either way, positions a put left out read as
+        zeros.
 
         `layers` optionally restricts the fetch to a subset of leaf paths
-        (e.g. ``{"slot0/k"}``); each selected segment is one ranged read,
-        the layerwise-retrieval pattern of ObjectCache.  Returns
+        (e.g. ``{"slot0/k"}``), the layerwise-retrieval pattern of
+        ObjectCache.  Each file the wanted pieces lie in is read once,
+        with one ranged read over those pieces.  Returns
         ``(cache, manifest)``."""
         with span("kv.get.fs"):
             man = self.manifest(key)
-            path = f"{self._dir(key)}/{_BLOCKS}"
-            want = [s for s in man["layers"]
-                    if layers is None or s["leaf"] in layers]
-            if layers is None:
-                # whole-prefix restore: one sequential read of the blocks file
-                raw_all = memoryview(self.fs.read_file(path))
-            else:
-                raws = [self.fs.read_file_range(path, s["offset"],
-                                                s["nbytes"]) for s in want]
+            want = [(seg, *_pieces(seg, key)) for seg in man["layers"]
+                    if layers is None or seg["leaf"] in layers]
+            ranges: dict[str, list[int]] = {}     # file -> [lo, hi)
+            for _, _, pieces in want:
+                for pc in pieces:
+                    lo, hi = pc["offset"], pc["offset"] + pc["nbytes"]
+                    r = ranges.setdefault(pc["file"], [lo, hi])
+                    r[0], r[1] = min(r[0], lo), max(r[1], hi)
+            bufs = {f: (lo, memoryview(self.fs.read_file_range(
+                        f"{self.root}/{f}", lo, hi - lo)))
+                    for f, (lo, hi) in ranges.items()}
         with span("kv.get.assemble"):
-            if layers is None:
-                raws = [raw_all[s["offset"]:s["offset"] + s["nbytes"]]
-                        for s in want]
-            per_leaf: dict[str, list[tuple[dict, object]]] = {}
-            for seg, raw in zip(want, raws):
-                if len(raw) != seg["nbytes"]:
-                    raise ValueError(
-                        f"kv block {seg['leaf']}[{seg['period']}] of {key}: "
-                        f"read {len(raw)} bytes, manifest says "
-                        f"{seg['nbytes']}")
-                per_leaf.setdefault(seg["leaf"], []).append((seg, raw))
-                self.stats["get_bytes"] += len(raw)
+            self.stats["get_bytes"] += sum(len(b) for _, b in bufs.values())
             self.stats["gets"] += 1
+            per_leaf: dict[str, list[tuple]] = {}
+            for seg, axis, pieces in want:
+                per_leaf.setdefault(seg["leaf"], []).append(
+                    (seg, axis, pieces))
             like_flat = dict(_flat_items(like)) if like is not None else {}
             leaves: dict[str, np.ndarray] = {}
-            for leaf, parts in per_leaf.items():
-                seg0 = parts[0][0]
+            for leaf, segs in per_leaf.items():
+                seg0 = segs[0][0]
                 dtype = np.dtype(seg0["dtype"])
-                full = tuple(seg0.get("full_shape", seg0["shape"]))
-                shape = (len(parts),) + full
+                full = _full_shape(seg0)
+                shape = (len(segs),) + full
                 tgt = like_flat.get(leaf)
                 if tgt is not None:
                     if str(tgt.dtype) != str(dtype):
@@ -335,16 +492,29 @@ class KVCacheStore:
                             f"{dtype} != cache dtype {tgt.dtype}")
                     # target per-row shape: drop the batch axis
                     shape = (tgt.shape[0],) + tuple(tgt.shape[2:])
-                    if shape[0] != len(parts):
+                    if shape[0] != len(segs):
                         raise ValueError(
-                            f"kv block {leaf!r}: {len(parts)} stored "
+                            f"kv block {leaf!r}: {len(segs)} stored "
                             f"periods, cache has {shape[0]}")
-                stored = tuple(seg0["shape"])
-                sel = _live_index(stored, full, shape[1:], man["cache_len"],
-                                  leaf)
+                resize = _resize_axis(full, shape[1:], man["cache_len"],
+                                      leaf)
                 out = np.zeros((shape[0], 1) + shape[1:], dtype)
-                for seg, raw in parts:
-                    src = np.frombuffer(raw, dtype=dtype).reshape(stored)
-                    out[seg["period"], 0][sel] = src[sel]
+                for seg, axis, pieces in segs:
+                    dst = out[seg["period"], 0]
+                    for pc in pieces:
+                        lo, buf = bufs[pc["file"]]
+                        raw = buf[pc["offset"] - lo:
+                                  pc["offset"] - lo + pc["nbytes"]]
+                        if len(raw) != pc["nbytes"]:
+                            raise ValueError(
+                                f"kv block {leaf}[{seg['period']}] of "
+                                f"{key}: read {len(raw)} bytes of "
+                                f"{pc['file']}, manifest says "
+                                f"{pc['nbytes']}")
+                        src = np.frombuffer(raw, dtype=dtype).reshape(
+                            pc["shape"])
+                        s_idx, d_idx = _placement(pc, axis, full, resize,
+                                                  shape[1:], leaf)
+                        dst[d_idx] = src[s_idx]
                 leaves[leaf] = out
             return _unflatten(leaves), man

@@ -48,6 +48,34 @@ def _live_cache(cache_len, **kw):
     return cache
 
 
+def _old_format(fs, man, full_shape=True):
+    """Rewrite stored snapshot `man` (a put without a base) as writers
+    before delta snapshots laid it out: each segment one (offset, nbytes,
+    shape) in its own ``<key>/blocks.bin``, with or without
+    ``full_shape``."""
+    (own,) = _files(man)
+    fs.rename(f"/b/kv/{own}", f"/b/kv/{man['key']}/blocks.bin")
+    old = {k: v for k, v in man.items() if k != "blocks_nbytes"}
+    old["layers"] = []
+    for seg in man["layers"]:
+        (pc,) = seg["pieces"]
+        layer = {"leaf": seg["leaf"], "period": seg["period"],
+                 "offset": pc["offset"], "nbytes": pc["nbytes"],
+                 "dtype": seg["dtype"], "shape": pc["shape"]}
+        if full_shape:
+            layer["full_shape"] = seg["full_shape"]
+        old["layers"].append(layer)
+    fs.write_file(f"/b/kv/{man['key']}/manifest.json",
+                  json.dumps(old).encode())
+    return old
+
+
+def _files(man):
+    """The blocks files, relative to the store root, that `man`'s pieces
+    lie in."""
+    return {pc["file"] for seg in man["layers"] for pc in seg["pieces"]}
+
+
 def _same_bytes(a, b):
     a, b = np.asarray(a), np.asarray(b)
     assert a.shape == b.shape and a.dtype == b.dtype
@@ -187,10 +215,16 @@ def test_put_with_pos_axes_stores_live_positions(workdir):
     for seg in man["layers"]:
         want = list(row[seg["leaf"]].shape[1:])
         assert seg["full_shape"] == want
+        (piece,) = seg["pieces"]
         if seg["leaf"].startswith("slot0/"):
             want[1] = 12                  # (heads, positions, head_dim)
-        assert seg["shape"] == want
-    assert len(fs.read_file(f"/b/kv/{man['key']}/blocks.bin")) == live
+            assert seg["axis"] == 1 and piece["pos"] == [0, 12]
+        else:
+            assert seg["axis"] is None and piece["pos"] is None
+        assert piece["shape"] == want
+    (own,) = _files(man)
+    assert own.startswith(f"{man['key']}/")
+    assert len(fs.read_file(f"/b/kv/{own}")) == live
     cl.close()
 
 
@@ -227,7 +261,8 @@ def test_state_and_wrapped_ring_stored_whole(workdir, cache_len):
     assert man["nbytes"] == sum(cache[a][b][:, 0].nbytes for a, b in
                                 (("slot0", "k"), ("slot0", "v"),
                                  ("slot1", "conv"), ("slot1", "ssm")))
-    assert all(seg["shape"] == seg["full_shape"] for seg in man["layers"])
+    assert all(pc["pos"] is None and pc["shape"] == seg["full_shape"]
+               for seg in man["layers"] for pc in seg["pieces"])
     got, _ = kv.get(man["key"], like=cache)
     _same_bytes(got["slot0"]["k"], cache["slot0"]["k"][:, :1])
     with pytest.raises(ValueError, match="resize"):   # wrapped: no resize
@@ -244,11 +279,7 @@ def test_snapshot_without_axes_still_restores(workdir):
     cache = _live_cache(12, kv_len=32)
     man = kv.put(np.arange(12, dtype=np.int32), cache)
     assert kv.stats["trimmed_bytes"] == 0
-    path = f"/b/kv/{man['key']}/manifest.json"
-    old = json.loads(fs.read_file(path))
-    for seg in old["layers"]:
-        del seg["full_shape"]
-    fs.write_file(path, json.dumps(old).encode())
+    _old_format(fs, man, full_shape=False)
     for like in (cache, None, _live_cache(12, kv_len=48, seed=1)):
         got, _ = kv.get(man["key"], like=like)
         kv_len = 32 if like is None else like["slot0"]["k"].shape[3]
@@ -301,6 +332,242 @@ def test_get_reads_only_shape_and_dtype_of_like(workdir):
     cl.close()
 
 
+# a cache with attention K/V only, K/V beside SSM state, state only, and a
+# ring: the K/V extent (32) is reached by every snapshot of its chain
+_KINDS = {"attention": (("slot0",), (8, 16, 21)),
+          "hybrid": (("slot0", "slot1"), (8, 16, 21)),
+          "state": (("slot1",), (8, 16, 21)),
+          "wrapped": (("slot0", "slot1"), (32, 40, 43))}
+
+
+def _kind_cache(kind, cache_len):
+    slots, _ = _KINDS[kind]
+    cache = _live_cache(min(cache_len, 32), kv_len=32)
+    return ({s: cache[s] for s in slots},
+            {s: _POS_AXES[s] for s in slots})
+
+
+def _live_bytes(cache, cache_len):
+    """Bytes of batch row 0 of `cache` in its first `cache_len` positions
+    (K/V) and its whole state."""
+    out = 0
+    for a, leaves in cache.items():
+        for b, x in leaves.items():
+            row = x[:, 0]
+            out += row[:, :, :cache_len].nbytes if a == "slot0" \
+                and cache_len < x.shape[3] else row.nbytes
+    return out
+
+
+def _np_cut(cuts):
+    """`KVCacheStore.put`'s `cut` in numpy, keeping each window it cut as
+    (start, size) in `cuts`."""
+    def cut(arr, start, size, axis):
+        cuts.append((start, size))
+        return np.take(arr, np.arange(start, start + size), axis=axis)
+    return cut
+
+
+@pytest.mark.parametrize("kind", sorted(_KINDS))
+@pytest.mark.parametrize("windowed", [False, True])
+def test_delta_chain_restores_like_whole(workdir, kind, windowed):
+    """base -> +block -> +tail: each delta put restores byte for byte as a
+    whole put of the same tokens, and writes only what its base lacks."""
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    whole = KVCacheStore(fs, "/b/whole", block_tokens=8)
+    toks = np.arange(100, 150, dtype=np.int32)
+    base, live = None, 0
+    for ln in _KINDS[kind][1]:
+        cache, axes = _kind_cache(kind, ln)
+        since = base["cache_len"] if base else 0
+        cuts = []
+        man = kv.put(toks[:ln], cache, pos_axes=axes, base=base,
+                     cut=_np_cut(cuts) if windowed else None)
+        # one block window a K/V leaf, holding every position written
+        assert all(st <= since and ln <= st + size and size == 8
+                   for st, size in cuts)
+        assert len(cuts) == (2 if windowed and "slot0" in cache
+                             and ln < 32 else 0)
+        ref = whole.put(toks[:ln], cache, pos_axes=axes)
+        live += _live_bytes(cache, ln)
+        assert man["nbytes"] == ref["nbytes"] == _live_bytes(cache, ln)
+        files = _files(man)
+        for like in (cache, None):
+            got, _ = kv.get(man["key"], like=like)
+            want, _ = whole.get(ref["key"], like=like)
+            for a, leaves in want.items():
+                for b, x in leaves.items():
+                    _same_bytes(got[a][b], x)
+                    _same_bytes(got[a][b], cache[a][b][:, :1])
+        base = man
+    # put_bytes and shared_bytes add up to the snapshots' live bytes
+    assert kv.stats["put_bytes"] + kv.stats["shared_bytes"] == live
+    assert whole.stats["put_bytes"] == live
+    assert whole.stats["shared_bytes"] == 0
+    delta = kind in ("attention", "hybrid")
+    assert (kv.stats["shared_bytes"] > 0) == delta
+    assert len(files) == (3 if delta else 1)
+    if delta:
+        # the tail put wrote positions 16..20 of K/V, and the state whole
+        k = cache["slot0"]["k"][:, 0]
+        state = _live_bytes({s: cache[s] for s in cache if s != "slot0"}, 0)
+        assert base["blocks_nbytes"] == 2 * k[:, :, 16:21].nbytes + state
+        assert {f.split("/")[0] for f in files} \
+            == {prefix_key(toks[:ln]) for ln in _KINDS[kind][1]}
+    cl.close()
+
+
+@pytest.mark.parametrize("full_shape", [True, False])
+def test_old_manifest_serves_as_base(workdir, full_shape):
+    """A base in an older layout — trimmed, with ``full_shape``, or whole
+    without it — is referenced as one piece of its own blocks."""
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    toks = np.arange(100, 150, dtype=np.int32)
+    old = _old_format(fs, kv.put(toks[:8], _live_cache(8), pos_axes=(
+        _POS_AXES if full_shape else None)), full_shape=full_shape)
+    cache = _live_cache(13)
+    man = kv.put(toks[:13], cache, pos_axes=_POS_AXES, base=old)
+    k = cache["slot0"]["k"][:, 0]
+    assert kv.stats["shared_bytes"] == 2 * k[:, :, :8].nbytes
+    (own,) = _files(man) - {f"{old['key']}/blocks.bin"}
+    assert own.startswith(f"{man['key']}/")
+    got, _ = kv.get(man["key"], like=cache)
+    for a, leaves in cache.items():
+        for b, x in leaves.items():
+            _same_bytes(got[a][b], x[:, :1])
+    cl.close()
+
+
+def test_layer_subset_reads_across_files(workdir):
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    toks = np.arange(100, 150, dtype=np.int32)
+    base = None
+    for ln in (8, 16, 21):
+        base = kv.put(toks[:ln], _live_cache(ln), pos_axes=_POS_AXES,
+                      base=base)
+    cache = _live_cache(21)
+    kv.stats["get_bytes"] = 0
+    got, _ = kv.get(base["key"], layers={"slot0/v"}, like=cache)
+    assert list(got) == ["slot0"] and list(got["slot0"]) == ["v"]
+    _same_bytes(got["slot0"]["v"], cache["slot0"]["v"][:, :1])
+    # one ranged read of each of the three files: V's live positions
+    assert kv.stats["get_bytes"] == cache["slot0"]["v"][:, 0, :, :21].nbytes
+    cl.close()
+
+
+@pytest.mark.parametrize("base_toks", ["diverged", "longer", "no_tokens"])
+def test_base_not_a_prefix_is_refused(workdir, base_toks):
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    toks = np.arange(100, 150, dtype=np.int32)
+    other = {"diverged": np.arange(8, dtype=np.int32),
+             "longer": toks[:20], "no_tokens": toks[:8]}[base_toks]
+    base = kv.put(other, _live_cache(len(other)), pos_axes=_POS_AXES)
+    if base_toks == "no_tokens":
+        base = {k: v for k, v in base.items() if k != "tokens"}
+    kv.stats["put_bytes"] = 0
+    cache = _live_cache(13)
+    man = kv.put(toks[:13], cache, pos_axes=_POS_AXES, base=base)
+    assert kv.stats["shared_bytes"] == 0
+    assert kv.stats["put_bytes"] == man["blocks_nbytes"] == man["nbytes"] \
+        == _live_bytes(cache, 13)
+    (own,) = _files(man)
+    assert own.startswith(f"{man['key']}/")
+    got, _ = kv.get(man["key"], like=cache)
+    _same_bytes(got["slot0"]["k"], cache["slot0"]["k"][:, :1])
+    cl.close()
+
+
+@pytest.mark.parametrize("base_len", [0, 8])
+def test_window_short_of_the_range_reads_whole_leaf(workdir, base_len):
+    """Where no block window holds every position a put writes (21
+    positions from 0, or 13 from 8, in windows of 8), nothing is cut: the
+    whole leaf is read, and the snapshot is the same."""
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
+    toks = np.arange(100, 150, dtype=np.int32)
+    base = kv.put(toks[:8], _live_cache(8), pos_axes=_POS_AXES) \
+        if base_len else None
+    cache, cuts = _live_cache(21), []
+    man = kv.put(toks[:21], cache, pos_axes=_POS_AXES, base=base,
+                 cut=_np_cut(cuts))
+    assert cuts == []
+    assert kv.stats["shared_bytes"] == \
+        2 * cache["slot0"]["k"][:, 0, :, :base_len].nbytes
+    got, _ = kv.get(man["key"], like=cache)
+    for a, leaves in cache.items():
+        for b, x in leaves.items():
+            _same_bytes(got[a][b], x[:, :1])
+    cl.close()
+
+
+class _HookedFS:
+    """An FS client that runs `hook` once, just before its first write of
+    a path containing `part`: a put on another client lands in between."""
+
+    def __init__(self, fs, part, hook):
+        self._fs, self._part, self._hook = fs, part, hook
+
+    def write_file(self, path, data):
+        if self._hook is not None and self._part in path:
+            hook, self._hook = self._hook, None
+            hook()
+        return self._fs.write_file(path, data)
+
+    def __getattr__(self, name):
+        return getattr(self._fs, name)
+
+
+@pytest.mark.parametrize("between", ["/blocks.", "/.manifest."])
+def test_racing_puts_of_one_prefix_restore_exactly(workdir, between):
+    """Two replicas store the same 21-token prefix at once, over different
+    bases (8 and 16), so their blocks differ: one's put runs whole inside
+    the other's, before its blocks or before its manifest.  The published
+    manifest, and a delta over each manifest either put returned, restore
+    byte for byte."""
+    cl = make_cluster(workdir)
+    fs_b = make_fs(cl)
+    toks = np.arange(100, 150, dtype=np.int32)
+    kv_b = KVCacheStore(fs_b, "/b/kv", block_tokens=8)
+    m8 = kv_b.put(toks[:8], _live_cache(8), pos_axes=_POS_AXES)
+    m16 = kv_b.put(toks[:16], _live_cache(16), pos_axes=_POS_AXES, base=m8)
+    cache = _live_cache(21)
+    won = {}
+
+    def other():
+        won["b"] = kv_b.put(toks[:21], cache, pos_axes=_POS_AXES, base=m16)
+
+    kv_a = KVCacheStore(_HookedFS(make_fs(cl),
+                                  between, other), "/b/kv", block_tokens=8)
+    won["a"] = kv_a.put(toks[:21], cache, pos_axes=_POS_AXES, base=m8)
+    assert won["a"] is not None and won["b"] is not None
+    assert won["a"]["blocks_nbytes"] != won["b"]["blocks_nbytes"]
+    got, _ = kv_b.get(prefix_key(toks[:21]), like=cache)
+    for a, leaves in cache.items():
+        for b, x in leaves.items():
+            _same_bytes(got[a][b], x[:, :1])
+    # a delta over either manifest: two 29-token prompts past the prefix
+    longer = _live_cache(29)
+    for i, side in enumerate("ab"):
+        tail = np.full(8, i, np.int32)
+        man = kv_b.put(np.concatenate([toks[:21], tail]), longer,
+                       pos_axes=_POS_AXES, base=won[side])
+        assert _files(won[side]) < _files(man)
+        got, _ = kv_b.get(man["key"], like=longer)
+        for a, leaves in longer.items():
+            for b, x in leaves.items():
+                _same_bytes(got[a][b], x[:, :1])
+    cl.close()
+
+
 def test_read_file_range(fs):
     data = bytes(range(256)) * 2048            # 512 KiB, 2 chunks
     fs.write_file("/b/rng.bin", data)
@@ -329,15 +596,24 @@ def _engine(arch, fs, kv_root=None, max_len=64, block_tokens=8):
     return ServingEngine(model, params, max_len=max_len, kvstore=kv), cfg
 
 
-def _record_snapshots(kv):
+def _record_snapshots(kv, windows=None):
     """Wrap `kv.put`/`kv.get` to keep, per key, the device cache each put
-    saw (as numpy) and each cache a get restored."""
+    saw (as numpy) and each cache a get restored; and in `windows` the
+    position extent of every device window a put cut."""
     import jax
 
     put_saw, got = {}, []
     real_put, real_get = kv.put, kv.get
 
     def put(tokens, cache, **kw):
+        if windows is not None and kw.get("cut") is not None:
+            real_cut = kw["cut"]
+
+            def cut(arr, start, size, axis):
+                out = real_cut(arr, start, size, axis)
+                windows.append(out.shape[axis])
+                return out
+            kw["cut"] = cut
         man = real_put(tokens, cache, **kw)
         if man is not None:
             put_saw[man["key"]] = jax.tree.map(np.asarray, cache)
@@ -359,13 +635,15 @@ def test_reuse_tokens_identical(workdir, arch):
     """Dense attention, SSM state, a sliding-window ring (window 32 at the
     reduced size, wrapped by the 40-token prompt) and two hybrids, the
     second with NoPE attention and an MoE in every layer: its snapshots mix
-    trimmed K/V with whole SSM state."""
+    trimmed K/V with whole SSM state.  A three-turn session then stores each
+    turn as a delta over the snapshot it restored."""
     import jax
 
     cl = make_cluster(workdir)
     fs = make_fs(cl, consistency="weak")
     engine, cfg = _engine(arch, fs, kv_root="/b/kv")
-    put_saw, got = _record_snapshots(engine.kvstore)
+    windows = []
+    put_saw, got = _record_snapshots(engine.kvstore, windows)
     rng = np.random.default_rng(3)
     prompt = rng.integers(0, cfg.vocab, size=21, dtype=np.int32)
 
@@ -395,11 +673,21 @@ def test_reuse_tokens_identical(workdir, arch):
         out, _ = engine.generate_with_reuse(longest, max_new=6)
         assert out == ref
 
+    # a session: each turn is the last prompt, its answer and a new message
+    turn = rng.integers(0, cfg.vocab, 13, dtype=np.int32)
+    for i in range(3):
+        ref = engine.generate([turn], max_new=6)[0]
+        out, info = engine.generate_with_reuse(turn, max_new=6)
+        assert out == ref
+        assert (info["reused_len"] > 0) == (i > 0)
+        turn = np.concatenate([turn, np.asarray(out, np.int32),
+                               rng.integers(0, cfg.vocab, 5, dtype=np.int32)])
+
     # every restore, and every stored snapshot read back, is byte for byte
     # the cache its put saw, zero tail included
     kv = engine.kvstore
     spec = engine.model.cache_spec(1, engine.max_len)
-    assert len(got) == 4
+    assert len(got) == 6
     restored = got + [(k, kv.get(k, like=spec)[0]) for k in put_saw]
     for key, tree in restored:
         for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(put_saw[key])):
@@ -411,6 +699,11 @@ def test_reuse_tokens_identical(workdir, arch):
     # state_bytes is the part of put_bytes with no position axis
     assert (kv.stats["state_bytes"] > 0) == (None in axes)
     assert (kv.stats["state_bytes"] < kv.stats["put_bytes"]) == has_positions
+    # puts past a restore write only what their base lacks, and no put is
+    # handed more of the device cache than one block window
+    assert (kv.stats["shared_bytes"] > 0) == has_positions
+    assert bool(windows) == has_positions
+    assert all(w <= kv.block_tokens for w in windows)
     cl.close()
 
 
