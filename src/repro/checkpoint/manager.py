@@ -85,23 +85,37 @@ class CheckpointManager:
         return max(steps) if steps else None
 
     def restore(self, step: int, like=None):
+        """The tree saved at `step`: numpy arrays by flat key, or, given
+        `like`, in `like`'s structure and dtypes.  Raises `ValueError`
+        naming the leaf where a leaf file's bytes do not match its
+        manifest's shape and dtype, or a leaf of `like` is missing from the
+        manifest: a partially published checkpoint must fail loudly, not
+        deserialize garbage."""
         d = f"{self.root}/step_{step}"
         manifest = json.loads(self.fs.read_file(f"{d}/manifest.json"))
         flat = {}
         for key, info in manifest["leaves"].items():
             raw = self.fs.read_file(f"{d}/{key}.bin")
+            want = int(np.prod(info["shape"], dtype=np.int64)) * \
+                np.dtype(info["dtype"]).itemsize
+            if len(raw) != want:
+                raise ValueError(
+                    f"checkpoint leaf {key!r} at {d}: {len(raw)} bytes on "
+                    f"disk, manifest says {info['dtype']}{info['shape']} "
+                    f"= {want} bytes")
             flat[key] = np.frombuffer(raw, dtype=info["dtype"]).reshape(
                 info["shape"])
         if like is None:
             return flat
         # rebuild into the structure of `like`
-        leaves_like = jax.tree_util.tree_flatten_with_path(like)[0]
-        rebuilt = []
-        for path, leaf in leaves_like:
-            key = ".".join(_key_str(k) for k in path)
-            arr = flat[key]
-            rebuilt.append(arr.astype(leaf.dtype, copy=False)
-                           if hasattr(leaf, "dtype") else arr)
+        leaves_like = list(_flatten(like))
+        missing = [key for key, _ in leaves_like if key not in flat]
+        if missing:
+            raise ValueError(f"checkpoint manifest at {d} is missing "
+                             f"leaves: {', '.join(missing)}")
+        rebuilt = [flat[key].astype(leaf.dtype, copy=False)
+                   if hasattr(leaf, "dtype") else flat[key]
+                   for key, leaf in leaves_like]
         treedef = jax.tree_util.tree_structure(like)
         return jax.tree_util.tree_unflatten(treedef, rebuilt)
 

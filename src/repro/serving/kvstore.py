@@ -49,10 +49,7 @@ only files whose manifests were committed before it.  Every stored byte
 still goes through the same durable write path: a delta only stops
 writing the same bytes twice.  Snapshots are immutable and this store
 deletes nothing; a future deleter must keep a blocks file while any
-committed manifest, or a manifest a put returned, references it.  Older
-manifests, whose segments are one (offset, nbytes, shape) in their own
-``<key>/blocks.bin``, with or without
-``full_shape``, still restore and may serve as a base.
+committed manifest, or a manifest a put returned, references it.
 The manifest is committed with the same write-then-rename discipline as
 `checkpoint/manager.py`: rename is a 2PC transaction, so a prefix either
 has a complete manifest or is invisible — a crashed writer never publishes
@@ -87,7 +84,6 @@ import numpy as np
 from ..core.fs import ObjcacheFS
 from ..core.spans import span
 
-_BLOCKS = "blocks.bin"       # a snapshot's one file, before delta puts
 _MANIFEST = "manifest.json"
 
 
@@ -123,48 +119,21 @@ def _unflatten(items: dict[str, np.ndarray]) -> dict:
     return out
 
 
-def _full_shape(seg: dict) -> tuple[int, ...]:
-    """The writer's allocated per-period shape of a segment (older writers
-    stored whole segments and recorded only ``shape``)."""
-    return tuple(seg["full_shape"] if "full_shape" in seg else seg["shape"])
-
-
-def _pieces(seg: dict, key: str) -> tuple[int | None, list[dict]]:
-    """A segment's per-period position axis and its pieces.  A segment of
-    an older manifest (snapshot `key`'s) is one piece of its own blocks,
-    holding the first positions along the one axis where its ``shape`` is
-    shorter than its ``full_shape``, or the whole array where they agree."""
-    if "pieces" in seg:
-        return seg["axis"], seg["pieces"]
-    shape = seg["shape"]
-    diff = [i for i, (s, f) in enumerate(zip(shape, _full_shape(seg)))
-            if s != f]
-    axis = diff[0] if len(diff) == 1 else None
-    return axis, [{"file": f"{key}/{_BLOCKS}", "offset": seg["offset"],
-                   "nbytes": seg["nbytes"], "shape": shape,
-                   "pos": None if axis is None else [0, shape[axis]]}]
-
-
-def _base_refs(seg: dict | None, key: str, dtype: str,
-               full: tuple[int, ...], axis: int, since: int
-               ) -> list[dict] | None:
-    """The pieces of snapshot `key`'s segment `seg` that hold positions
+def _base_refs(seg: dict | None, dtype: str, full: tuple[int, ...],
+               axis: int, since: int) -> list[dict] | None:
+    """The pieces of a base's segment `seg` that hold positions
     ``[0, since)`` of a leaf laid out as `dtype`, per-period `full` shape
-    and position `axis`; None where the segment cannot serve."""
-    if seg is None or seg["dtype"] != dtype or _full_shape(seg) != full:
+    and position `axis`; None where the segment cannot serve, as one
+    stored whole cannot."""
+    if seg is None or seg["dtype"] != dtype or seg["axis"] != axis \
+            or tuple(seg["full_shape"]) != full:
         return None
-    seg_axis, pieces = _pieces(seg, key)
-    if len(pieces) == 1 and pieces[0]["pos"] is None:
-        # stored whole: its first `since` positions are the ones wanted
-        if tuple(pieces[0]["shape"]) != full:
-            return None
-        return [{**pieces[0], "pos": [0, since]}]
     end = 0
-    for p in pieces:
+    for p in seg["pieces"]:
         if p["pos"] is None or p["pos"][0] != end:
             return None
         end = p["pos"][1]
-    return pieces if seg_axis == axis and end == since else None
+    return seg["pieces"] if end == since else None
 
 
 def _resize_axis(full: tuple[int, ...], target: tuple[int, ...],
@@ -307,7 +276,7 @@ class KVCacheStore:
         key = prefix_key(tokens)
         d = self._dir(key)
         axes = dict(_flat_items(pos_axes)) if pos_axes is not None else {}
-        since, base_key, base_segs = self._base(base, tokens)
+        since, base_segs = self._base(base, tokens)
         with span("kv.put"):
             with span("kv.put.fs"):
                 stored = self.fs.exists(f"{d}/{_MANIFEST}")
@@ -328,7 +297,7 @@ class KVCacheStore:
                         "ax": ax, "part": ax is not None and n < shape[ax],
                         "lo": 0, "start": 0, "refs": [[]] * shape[0]}
                 if item["part"]:
-                    refs = [_base_refs(base_segs.get((leaf, p)), base_key,
+                    refs = [_base_refs(base_segs.get((leaf, p)),
                                        str(arr.dtype), item["full"], ax - 2,
                                        since) for p in range(shape[0])]
                     if since and None not in refs:
@@ -401,20 +370,18 @@ class KVCacheStore:
         return manifest
 
     @staticmethod
-    def _base(base: dict | None, tokens: np.ndarray
-              ) -> tuple[int, str | None, dict]:
-        """``(cache_len, key, segments by (leaf, period))`` of a base
-        whose tokens are a proper prefix of `tokens`; ``(0, None, {})``
-        for any other."""
+    def _base(base: dict | None, tokens: np.ndarray) -> tuple[int, dict]:
+        """``(cache_len, segments by (leaf, period))`` of a base whose
+        tokens are a proper prefix of `tokens`; ``(0, {})`` for any
+        other."""
         if base is None:
-            return 0, None, {}
+            return 0, {}
         prefix = np.asarray(base.get("tokens", ()), np.int32).reshape(-1)
         m = prefix.size
         if not (0 < m < tokens.size and base.get("cache_len") == m
                 and np.array_equal(prefix, tokens[:m])):
-            return 0, None, {}
-        return m, base["key"], {(s["leaf"], s["period"]): s
-                                for s in base["layers"]}
+            return 0, {}
+        return m, {(s["leaf"], s["period"]): s for s in base["layers"]}
 
     def lookup(self, tokens, cap: int | None = None
                ) -> tuple[int, str] | None:
@@ -459,11 +426,11 @@ class KVCacheStore:
         ``(cache, manifest)``."""
         with span("kv.get.fs"):
             man = self.manifest(key)
-            want = [(seg, *_pieces(seg, key)) for seg in man["layers"]
+            want = [seg for seg in man["layers"]
                     if layers is None or seg["leaf"] in layers]
             ranges: dict[str, list[int]] = {}     # file -> [lo, hi)
-            for _, _, pieces in want:
-                for pc in pieces:
+            for seg in want:
+                for pc in seg["pieces"]:
                     lo, hi = pc["offset"], pc["offset"] + pc["nbytes"]
                     r = ranges.setdefault(pc["file"], [lo, hi])
                     r[0], r[1] = min(r[0], lo), max(r[1], hi)
@@ -473,16 +440,14 @@ class KVCacheStore:
         with span("kv.get.assemble"):
             self.stats["get_bytes"] += sum(len(b) for _, b in bufs.values())
             self.stats["gets"] += 1
-            per_leaf: dict[str, list[tuple]] = {}
-            for seg, axis, pieces in want:
-                per_leaf.setdefault(seg["leaf"], []).append(
-                    (seg, axis, pieces))
+            per_leaf: dict[str, list[dict]] = {}
+            for seg in want:
+                per_leaf.setdefault(seg["leaf"], []).append(seg)
             like_flat = dict(_flat_items(like)) if like is not None else {}
             leaves: dict[str, np.ndarray] = {}
             for leaf, segs in per_leaf.items():
-                seg0 = segs[0][0]
-                dtype = np.dtype(seg0["dtype"])
-                full = _full_shape(seg0)
+                dtype = np.dtype(segs[0]["dtype"])
+                full = tuple(segs[0]["full_shape"])
                 shape = (len(segs),) + full
                 tgt = like_flat.get(leaf)
                 if tgt is not None:
@@ -499,9 +464,9 @@ class KVCacheStore:
                 resize = _resize_axis(full, shape[1:], man["cache_len"],
                                       leaf)
                 out = np.zeros((shape[0], 1) + shape[1:], dtype)
-                for seg, axis, pieces in segs:
+                for seg in segs:
                     dst = out[seg["period"], 0]
-                    for pc in pieces:
+                    for pc in seg["pieces"]:
                         lo, buf = bufs[pc["file"]]
                         raw = buf[pc["offset"] - lo:
                                   pc["offset"] - lo + pc["nbytes"]]
@@ -513,8 +478,8 @@ class KVCacheStore:
                                 f"{pc['nbytes']}")
                         src = np.frombuffer(raw, dtype=dtype).reshape(
                             pc["shape"])
-                        s_idx, d_idx = _placement(pc, axis, full, resize,
-                                                  shape[1:], leaf)
+                        s_idx, d_idx = _placement(pc, seg["axis"], full,
+                                                  resize, shape[1:], leaf)
                         dst[d_idx] = src[s_idx]
                 leaves[leaf] = out
             return _unflatten(leaves), man

@@ -61,16 +61,29 @@ def test_model_store_and_engine_generate(workdir):
     cl.close()
 
 
-def test_model_store_load_missing_leaf_and_dtype_mismatch(workdir):
+_LOADERS = {
+    "model_store": lambda fs, root, like: ModelStore(fs, root).load(
+        0, like=like)[0],
+    "checkpoint": lambda fs, root, like: CheckpointManager(fs, root).restore(
+        0, like=like),
+}
+
+
+@pytest.mark.parametrize("loader", sorted(_LOADERS))
+def test_model_store_load_missing_leaf_and_dtype_mismatch(workdir, loader):
     """A manifest that drops a leaf or lies about a dtype must fail loudly
-    (named leaf in the message), never deserialize garbage."""
+    (named leaf in the message), never deserialize garbage: the model
+    store reads through the checkpoint's one reader, and so does a
+    training resume."""
     cl = make_cluster(workdir)
     fs = make_fs(cl, consistency="weak")
     cfg = get_reduced("qwen3-0.6b")
     model = build_model(cfg)
     state, _ = train_state_init(model, jax.random.PRNGKey(0), max_seq=32)
     CheckpointManager(fs, "/b/models/m").save(0, state.params)
-    store = ModelStore(fs, "/b/models/m")
+
+    def load():
+        return _LOADERS[loader](fs, "/b/models/m", state.params)
     man_path = "/b/models/m/step_0/manifest.json"
     manifest = json.loads(fs.read_file(man_path))
     victim = sorted(manifest["leaves"])[0]
@@ -81,19 +94,20 @@ def test_model_store_load_missing_leaf_and_dtype_mismatch(workdir):
                                     if k != victim}}
     fs.write_file(man_path, json.dumps(broken).encode())
     with pytest.raises(ValueError, match="missing leaves") as ei:
-        store.load(0, like=state.params)
+        load()
     assert victim in str(ei.value)
 
     # dtype mismatch: manifest claims a wider dtype than the bytes on disk
     lied = json.loads(json.dumps(manifest))
     lied["leaves"][victim]["dtype"] = "float64"
     fs.write_file(man_path, json.dumps(lied).encode())
-    with pytest.raises(ValueError, match="bytes on disk"):
-        store.load(0, like=state.params)
+    with pytest.raises(ValueError, match="bytes on disk") as ei:
+        load()
+    assert victim in str(ei.value)
 
     # restored manifest loads fine again
     fs.write_file(man_path, json.dumps(manifest).encode())
-    params, _ = store.load(0, like=state.params)
+    params = load()
     for a, b in zip(jax.tree.leaves(state.params), jax.tree.leaves(params)):
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))

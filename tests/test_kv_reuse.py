@@ -5,8 +5,6 @@ round-trips, shape adaptation, layer-ranged reads) plus the JAX serving
 integration: the same prompt must emit identical tokens with and without
 KV-prefix reuse, including across a simulated scale-down/warm-restart."""
 
-import json
-
 import numpy as np
 import pytest
 
@@ -46,28 +44,6 @@ def _live_cache(cache_len, **kw):
     for leaf in ("k", "v"):
         cache["slot0"][leaf][:, :, :, cache_len:] = 0
     return cache
-
-
-def _old_format(fs, man, full_shape=True):
-    """Rewrite stored snapshot `man` (a put without a base) as writers
-    before delta snapshots laid it out: each segment one (offset, nbytes,
-    shape) in its own ``<key>/blocks.bin``, with or without
-    ``full_shape``."""
-    (own,) = _files(man)
-    fs.rename(f"/b/kv/{own}", f"/b/kv/{man['key']}/blocks.bin")
-    old = {k: v for k, v in man.items() if k != "blocks_nbytes"}
-    old["layers"] = []
-    for seg in man["layers"]:
-        (pc,) = seg["pieces"]
-        layer = {"leaf": seg["leaf"], "period": seg["period"],
-                 "offset": pc["offset"], "nbytes": pc["nbytes"],
-                 "dtype": seg["dtype"], "shape": pc["shape"]}
-        if full_shape:
-            layer["full_shape"] = seg["full_shape"]
-        old["layers"].append(layer)
-    fs.write_file(f"/b/kv/{man['key']}/manifest.json",
-                  json.dumps(old).encode())
-    return old
 
 
 def _files(man):
@@ -271,15 +247,14 @@ def test_state_and_wrapped_ring_stored_whole(workdir, cache_len):
 
 
 def test_snapshot_without_axes_still_restores(workdir):
-    """Whole snapshots — written without `pos_axes`, or by a writer whose
-    manifest has no ``full_shape`` — restore as before."""
+    """A snapshot put without `pos_axes` is stored whole and restores,
+    with and without `like`, at the writer's and at another `max_len`."""
     cl = make_cluster(workdir)
     fs = make_fs(cl, consistency="weak")
     kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
     cache = _live_cache(12, kv_len=32)
     man = kv.put(np.arange(12, dtype=np.int32), cache)
     assert kv.stats["trimmed_bytes"] == 0
-    _old_format(fs, man, full_shape=False)
     for like in (cache, None, _live_cache(12, kv_len=48, seed=1)):
         got, _ = kv.get(man["key"], like=like)
         kv_len = 32 if like is None else like["slot0"]["k"].shape[3]
@@ -419,29 +394,6 @@ def test_delta_chain_restores_like_whole(workdir, kind, windowed):
     cl.close()
 
 
-@pytest.mark.parametrize("full_shape", [True, False])
-def test_old_manifest_serves_as_base(workdir, full_shape):
-    """A base in an older layout — trimmed, with ``full_shape``, or whole
-    without it — is referenced as one piece of its own blocks."""
-    cl = make_cluster(workdir)
-    fs = make_fs(cl, consistency="weak")
-    kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
-    toks = np.arange(100, 150, dtype=np.int32)
-    old = _old_format(fs, kv.put(toks[:8], _live_cache(8), pos_axes=(
-        _POS_AXES if full_shape else None)), full_shape=full_shape)
-    cache = _live_cache(13)
-    man = kv.put(toks[:13], cache, pos_axes=_POS_AXES, base=old)
-    k = cache["slot0"]["k"][:, 0]
-    assert kv.stats["shared_bytes"] == 2 * k[:, :, :8].nbytes
-    (own,) = _files(man) - {f"{old['key']}/blocks.bin"}
-    assert own.startswith(f"{man['key']}/")
-    got, _ = kv.get(man["key"], like=cache)
-    for a, leaves in cache.items():
-        for b, x in leaves.items():
-            _same_bytes(got[a][b], x[:, :1])
-    cl.close()
-
-
 def test_layer_subset_reads_across_files(workdir):
     cl = make_cluster(workdir)
     fs = make_fs(cl, consistency="weak")
@@ -461,15 +413,20 @@ def test_layer_subset_reads_across_files(workdir):
     cl.close()
 
 
-@pytest.mark.parametrize("base_toks", ["diverged", "longer", "no_tokens"])
+@pytest.mark.parametrize("base_toks", ["diverged", "longer", "no_tokens",
+                                       "axis_less"])
 def test_base_not_a_prefix_is_refused(workdir, base_toks):
+    """A base that is not a proper prefix, or whose leaves were stored
+    whole (put without `pos_axes`), is no base: everything is written."""
     cl = make_cluster(workdir)
     fs = make_fs(cl, consistency="weak")
     kv = KVCacheStore(fs, "/b/kv", block_tokens=8)
     toks = np.arange(100, 150, dtype=np.int32)
     other = {"diverged": np.arange(8, dtype=np.int32),
-             "longer": toks[:20], "no_tokens": toks[:8]}[base_toks]
-    base = kv.put(other, _live_cache(len(other)), pos_axes=_POS_AXES)
+             "longer": toks[:20], "no_tokens": toks[:8],
+             "axis_less": toks[:8]}[base_toks]
+    base = kv.put(other, _live_cache(len(other)), pos_axes=(
+        None if base_toks == "axis_less" else _POS_AXES))
     if base_toks == "no_tokens":
         base = {k: v for k, v in base.items() if k != "tokens"}
     kv.stats["put_bytes"] = 0
@@ -481,7 +438,9 @@ def test_base_not_a_prefix_is_refused(workdir, base_toks):
     (own,) = _files(man)
     assert own.startswith(f"{man['key']}/")
     got, _ = kv.get(man["key"], like=cache)
-    _same_bytes(got["slot0"]["k"], cache["slot0"]["k"][:, :1])
+    for a, leaves in cache.items():
+        for b, x in leaves.items():
+            _same_bytes(got[a][b], x[:, :1])
     cl.close()
 
 
@@ -704,6 +663,41 @@ def test_reuse_tokens_identical(workdir, arch):
     assert (kv.stats["shared_bytes"] > 0) == has_positions
     assert bool(windows) == has_positions
     assert all(w <= kv.block_tokens for w in windows)
+    cl.close()
+
+
+@pytest.mark.parametrize("path", ["generate", "cold", "warm"])
+def test_one_decode_call_per_prefill_and_output_token(workdir, path):
+    """A wrapper set in the engine's `_decode` after construction, as the
+    benchmark sets one, sees one call per prefill step and one per output
+    token after the first, on both entry points."""
+    cl = make_cluster(workdir)
+    fs = make_fs(cl, consistency="weak")
+    engine, cfg = _engine("qwen3-0.6b", fs, kv_root="/b/kv")
+    prompt = np.random.default_rng(7).integers(0, cfg.vocab, 21,
+                                               dtype=np.int32)
+    if path == "warm":
+        engine.generate_with_reuse(prompt, max_new=2)
+    calls, real = [], engine._decode
+
+    def tap(*a):
+        calls.append(a[3])
+        return real(*a)
+    engine._decode = tap
+    max_new = 5
+    if path == "generate":
+        (out,) = engine.generate([prompt], max_new=max_new)
+        steps = len(prompt)
+    else:
+        out, info = engine.generate_with_reuse(prompt, max_new=max_new)
+        steps = info["prefill_steps"]
+        assert steps == (1 if path == "warm" else len(prompt))
+    assert len(out) == max_new
+    assert len(calls) == steps + max_new - 1
+    # each call at the next cache position
+    first = len(prompt) - steps
+    assert [int(c) for c in calls] == list(range(first,
+                                                 first + len(calls)))
     cl.close()
 
 
